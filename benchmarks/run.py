@@ -16,6 +16,8 @@ import argparse
 import time
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 from . import (bench_3dmemory, bench_dse, bench_mappings,
                bench_memory_sweep, bench_roofline, bench_serving,
                bench_solver, bench_specdecode, bench_validation)
@@ -48,6 +50,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.smoke:
         args.quick = True
+    use_compile_cache()
 
     if args.only:
         names = [args.only]

@@ -27,7 +27,7 @@ def main():
 
     from repro.configs import get_config
     from repro.launch import hlocost
-    from repro.launch.mesh import make_axis_rules
+    from repro.launch.mesh import make_axis_rules, make_mesh
     from repro.launch.shardings import batch_shardings, param_shardings
     from repro.models import init_params, loss_fn, synth_batch
     from repro.parallel.logical import use_rules
@@ -49,7 +49,7 @@ def main():
           f"comm bytes/block={sol.comm_bytes / 1e6:.1f} MB")
 
     # --- 2. real sharded step on the local 2x4 mesh ------------------------
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_axis_rules(mesh, cfg)
     with mesh, use_rules(rules, mesh):
         ps = param_shardings(cfg, mesh)

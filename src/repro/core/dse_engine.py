@@ -55,6 +55,7 @@ import pickle
 import sys
 import time
 import warnings
+from multiprocessing import context as _mpc
 from typing import Callable, Iterable, Iterator, Sequence
 
 from ..systems.system import SystemSpec
@@ -168,15 +169,78 @@ def pareto_frontier(points: Sequence[DesignPoint],
 _WORKER_CTX: dict = {}
 
 
-def _init_worker_shared(handle: StoreHandle) -> None:
-    """Pool-worker initializer: attach a fresh connection to the sweep's
-    shared memo store.  Runs before any task in every worker, for every
-    start method — fork children must not reuse the parent's socket or
+# Pool workers (and the memo store's server process) plan with numpy, and
+# the parent may hold an accelerator: a chip belongs to one process, so a
+# child that initialized JAX's default backend would fail or hang. Every
+# process the engine starts is therefore born with ``JAX_PLATFORMS=cpu``:
+# the parent's environment carries it only while ``start()`` runs, so the
+# parent's own JAX, which read its platforms at import, is untouched. Under
+# forkserver a child inherits the server's environment, which is pinned
+# too when the engine's first start brings the server up.
+class _CpuPinnedStart:
+    def start(self):
+        old = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            super().start()
+        finally:
+            if old is None:
+                os.environ.pop("JAX_PLATFORMS", None)
+            else:
+                os.environ["JAX_PLATFORMS"] = old
+
+
+class _CpuForkProcess(_CpuPinnedStart, _mpc.ForkProcess):
+    pass
+
+
+class _CpuSpawnProcess(_CpuPinnedStart, _mpc.SpawnProcess):
+    pass
+
+
+class _CpuForkServerProcess(_CpuPinnedStart, _mpc.ForkServerProcess):
+    pass
+
+
+class _CpuForkContext(_mpc.ForkContext):
+    Process = _CpuForkProcess
+
+
+class _CpuSpawnContext(_mpc.SpawnContext):
+    Process = _CpuSpawnProcess
+
+
+class _CpuForkServerContext(_mpc.ForkServerContext):
+    Process = _CpuForkServerProcess
+
+
+_CPU_CONTEXTS = {"fork": _CpuForkContext, "spawn": _CpuSpawnContext,
+                 "forkserver": _CpuForkServerContext}
+
+
+def _init_worker(handle: StoreHandle | None) -> None:
+    """Pool-worker initializer, run before any task in every worker, for
+    every start method.
+
+    It first pins the worker's JAX to the CPU platform again, for a worker
+    that was not born pinned: one forked from a forkserver that other code
+    brought up, or started through a caller's own ``mp_context`` object.
+    Such a worker may already have imported jax (through ``__main__``), so
+    the config is updated too, not only the environment; nothing
+    initializes a backend before either.
+
+    It then attaches a fresh connection to the sweep's shared memo store,
+    if there is one: fork children must not reuse the parent's socket or
     lock-owning fd, so inheriting the parent's attached client is never
-    enough.  The exit hook flushes whatever the client still buffers
+    enough. The exit hook flushes whatever the client still buffers
     (trailing puts, stats deltas) when the pool retires the worker; it is
     a ``multiprocessing.util.Finalize``, NOT ``atexit`` — pool children
     leave via ``os._exit``, which skips atexit handlers."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+    if handle is None:
+        return
     from multiprocessing.util import Finalize
 
     client = handle.connect()
@@ -915,10 +979,13 @@ class DSEEngine:
         return "spawn"
 
     def _mp_context(self) -> multiprocessing.context.BaseContext:
+        """The context the engine's processes start from: a caller's own
+        context object as given, else the picked method's, with every
+        start pinned to the CPU (see :class:`_CpuPinnedStart`)."""
         if (self.mp_context is not None
                 and not isinstance(self.mp_context, str)):
             return self.mp_context
-        return multiprocessing.get_context(self._start_method())
+        return _CPU_CONTEXTS[self._start_method()]()
 
     # -- shared memo store (one per parallel sweep) --------------------------
     def _open_shared_store(self):
@@ -982,11 +1049,11 @@ class DSEEngine:
         store.close()
 
     def _pool_kwargs(self, store) -> dict:
-        """Extra ``ProcessPoolExecutor`` kwargs wiring workers to ``store``."""
-        if store is None:
-            return {}
-        return {"initializer": _init_worker_shared,
-                "initargs": (store.handle(),)}
+        """Extra ``ProcessPoolExecutor`` kwargs: every worker is wired to
+        ``store`` and pinned to the CPU once more (see
+        :func:`_init_worker`)."""
+        return {"initializer": _init_worker,
+                "initargs": (None if store is None else store.handle(),)}
 
     def _pool(self, workers: int, store):
         """Pool acquisition: the warm session pool when one is live

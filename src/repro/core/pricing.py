@@ -21,11 +21,12 @@ Backends
     The default. Stacked float64 columns, elementwise ops.
 ``jax``
     ``jax.vmap`` of the same formula over the batch axis, run under
-    ``jax.experimental.enable_x64`` so every op is IEEE double. Eager vmap
-    on CPU is **bit-identical** to the numpy backend (and hence to the
-    scalar reference); pass ``jit=True`` for an XLA-compiled variant that
-    may fuse multiplies into FMAs and differ in the last ulp — fast, but
-    not certified element-identical.
+    ``jax.enable_x64(True)`` on the host CPU device (:func:`host_cpu_device`,
+    even where an accelerator is the default) so every op is IEEE double.
+    Eager vmap on CPU is **bit-identical** to the numpy backend (and hence
+    to the scalar reference); pass ``jit=True`` for an XLA-compiled variant
+    that may fuse multiplies into FMAs and differ in the last ulp — fast,
+    but not certified element-identical.
 ``pallas``
     The same formula lowered as a Pallas kernel tiled over the batch
     (candidate) axis — :mod:`repro.kernels.pricing`. Runs in interpret
@@ -429,11 +430,9 @@ def _dispatch(formula, cols: Mapping[str, np.ndarray], backend: str,
         out = pallas_columns_f32(formula, cols)
     else:
         import jax
-        from jax.experimental import enable_x64
+        import jax.numpy as jnp
 
-        with enable_x64():
-            import jax.numpy as jnp
-
+        with jax.enable_x64(True), jax.default_device(host_cpu_device()):
             fn = jax.vmap(lambda row: formula(jnp, row))
             if jit:
                 fn = jax.jit(fn)
@@ -442,6 +441,22 @@ def _dispatch(formula, cols: Mapping[str, np.ndarray], backend: str,
                 {k: jnp.asarray(a, dtype=jnp.float64)
                  for k, a in cols.items()}).items()}
     return {k: np.asarray(a) for k, a in out.items()}
+
+
+def host_cpu_device():
+    """The host CPU device on which the exact ``jax`` and ``pallas``
+    backends run. They are f64 reference twins of numpy, so they never run
+    on an accelerator, where float64 is not native; a process whose JAX has
+    no CPU backend gets a clear error instead of a demoted precision."""
+    import jax
+
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "the exact 'jax'/'pallas' pricing backends run in float64 on the "
+            "host CPU device, and this JAX has no CPU backend (JAX_PLATFORMS "
+            "leaves out 'cpu'); use 'numpy' or 'pallas-compiled'") from e
 
 
 def price_plans(plans: Sequence[PlanVector] | Mapping[str, np.ndarray],

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import SMEM, tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -90,7 +89,7 @@ def decode_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel,
         grid=(b * hkv, num_k),
         in_specs=[
-            pl.BlockSpec(memory_space=SMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
             pl.BlockSpec((1, n_rep, hd), lambda ih, ik: (ih, 0, 0)),
             pl.BlockSpec((1, block_k, hd), lambda ih, ik: (ih, ik, 0)),
             pl.BlockSpec((1, block_k, hd), lambda ih, ik: (ih, ik, 0)),
@@ -108,7 +107,7 @@ def decode_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((n_rep,), jnp.float32),
             pltpu.VMEM((n_rep,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(len_arr, qr, kr, vr)

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -119,7 +118,7 @@ def flash_attention_fwd_lse(q, k, v, causal=True, block_q=128, block_k=128,
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32),
                         pltpu.VMEM((block_q,), jnp.float32),
                         pltpu.VMEM((block_q,), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
@@ -271,7 +270,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=True,
                    jax.ShapeDtypeStruct((b * h, sk, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr, dor, lser, ddr)
@@ -298,7 +297,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, causal=True,
                                lambda ih, iq, ik: (ih, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr, dor, lser, ddr)

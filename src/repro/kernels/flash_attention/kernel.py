@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -124,7 +123,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)

@@ -11,8 +11,10 @@ reference is preserved by construction.
 
 Bit-exactness story
 -------------------
-The kernel runs in **interpret mode on CPU under ``enable_x64``** — every
-op is the IEEE-double XLA op the certified ``jax`` backend uses. Two
+The kernel runs in **interpret mode on the host CPU device under
+``jax.enable_x64(True)``** — every op is the IEEE-double XLA op the
+certified ``jax`` backend uses. It is pinned to the CPU device even where
+an accelerator is the default, so it never runs at a demoted precision. Two
 compiled-path hazards remain, each pinned off separately:
 
 * LLVM contracts ``a*b + c`` into an FMA inside a fused computation (the
@@ -62,7 +64,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.experimental import pallas as pl
 
 #: Candidates per grid step. Large enough to amortize interpret-mode
@@ -192,7 +193,9 @@ def run_columns(formula, cols, out_names, tile: int = DEFAULT_TILE,
     in_names = tuple(cols)
     n = len(next(iter(cols.values())))
     padded = padded_length(n, tile)
-    with enable_x64():
+    from repro.core.pricing import host_cpu_device
+
+    with jax.enable_x64(True), jax.default_device(host_cpu_device()):
         compiled = _compiled_call(formula, in_names, tuple(out_names),
                                   padded, tile, interpret)
         ins = [jnp.asarray(np.pad(np.asarray(cols[name], dtype=np.float64),
@@ -243,6 +246,27 @@ def _compiled_call_f32(formula, in_names: tuple[str, ...],
     ))
 
 
+def resolve_interpret(interpret: bool | str) -> bool:
+    """``"auto"`` resolves to the interpret-mode twin on the CPU backend
+    and to the real (Mosaic) lowering on an accelerator."""
+    if interpret == "auto":
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
+
+
+def f32_call(formula, in_names, out_names, n: int,
+             interpret: bool | str = "auto"):
+    """The jitted f32 kernel that prices ``n`` rows of the ``in_names``
+    columns, and the (sublane-rows, 128) float32 shape of each of its
+    ``1 + len(in_names)`` arguments: the validity column, then one block
+    per input column. :func:`run_columns_f32` runs this callable and
+    :func:`.ops.lower_f32` lowers it, so both see one program."""
+    padded = padded_length(n, F32_BLOCK)
+    call = _compiled_call_f32(formula, tuple(in_names), tuple(out_names),
+                              padded, resolve_interpret(interpret))
+    return call, (padded // F32_LANES, F32_LANES)
+
+
 def run_columns_f32(formula, cols, out_names,
                     interpret: bool | str = "auto"
                     ) -> dict[str, np.ndarray]:
@@ -261,23 +285,18 @@ def run_columns_f32(formula, cols, out_names,
     hardware. Outputs are float32 (:mod:`.drift` re-prices decisions
     exactly; see the module docstring's numerics contract).
     """
-    in_names = tuple(cols)
     n = len(next(iter(cols.values())))
-    padded = padded_length(n, F32_BLOCK)
-    rows = padded // F32_LANES
-    if interpret == "auto":
-        interpret = jax.default_backend() == "cpu"
-    call = _compiled_call_f32(formula, in_names, tuple(out_names), padded,
-                              bool(interpret))
+    call, shape = f32_call(formula, tuple(cols), out_names, n, interpret)
+    padded = shape[0] * shape[1]
 
     def block(col: np.ndarray) -> jnp.ndarray:
         flat = np.pad(np.asarray(col, dtype=np.float32), (0, padded - n))
-        return jnp.asarray(flat.reshape(rows, F32_LANES))
+        return jnp.asarray(flat.reshape(shape))
 
     valid = np.zeros(padded, dtype=np.float32)
     valid[:n] = 1.0
-    ins = [jnp.asarray(valid.reshape(rows, F32_LANES))]
-    ins += [block(cols[name]) for name in in_names]
+    ins = [jnp.asarray(valid.reshape(shape))]
+    ins += [block(cols[name]) for name in cols]
     outs = call(*ins)
     return {name: np.asarray(out).reshape(-1)[:n]
             for name, out in zip(out_names, outs)}

@@ -11,9 +11,11 @@ from __future__ import annotations
 
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from .kernel import DEFAULT_TILE, run_columns, run_columns_f32
+from .kernel import DEFAULT_TILE, f32_call, run_columns, run_columns_f32
 
 
 @functools.lru_cache(maxsize=256)
@@ -65,6 +67,20 @@ def pallas_columns_f32(formula, cols,
         if flag:
             out[key] = out[key].astype(np.bool_)
     return out
+
+
+def lower_f32(formula, in_names, n: int, interpret: bool | str = "auto",
+              sharding=None):
+    """Lower, without running, the compiled f32 kernel that
+    :func:`pallas_columns_f32` runs for ``n`` rows of the ``in_names``
+    columns: the same cached callable, on the same argument shapes
+    (:func:`.kernel.f32_call`). Its HLO holds a ``tpu_custom_call`` exactly
+    when the kernel is compiled rather than interpreted; the compile tests
+    pass a ``sharding`` on a described TPU device."""
+    names, _ = _probe_outputs(formula, tuple(in_names))
+    call, shape = f32_call(formula, in_names, names, n, interpret)
+    arg = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+    return call.lower(*[arg] * (1 + len(in_names)))
 
 
 def certify(n: int = 512, seed: int = 0,

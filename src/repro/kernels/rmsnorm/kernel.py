@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
-
 
 def _rms_kernel(x_ref, w_ref, r_ref, y_ref, rout_ref, *, eps: float,
                 has_residual: bool):
@@ -56,7 +54,7 @@ def fused_rmsnorm_fwd(x: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((t, d), x.dtype),
             jax.ShapeDtypeStruct((t, d), x.dtype),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w, res)

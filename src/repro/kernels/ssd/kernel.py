@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
-
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, da_ref, y_ref, hout_ref,
                 h_ref, *, num_chunks: int):
@@ -102,7 +100,7 @@ def ssd_chunk_fwd(x: jax.Array, dt: jax.Array, B: jax.Array, C: jax.Array,
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, B, C, dA)

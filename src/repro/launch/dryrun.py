@@ -302,11 +302,17 @@ def main():
     else:
         targets.append((args.arch, args.shape))
 
-    run_dryrun(targets, pods=pods, force=args.force,
-               fsdp=args.fsdp, remat=args.remat,
-               moe_dispatch=args.moe_dispatch, accum=args.accum,
-               kv_replicate=args.kv_replicate, bf16_params=args.bf16_params,
-               bf16_ar=args.bf16_ar, cp_decode=args.cp_decode)
+    results = run_dryrun(targets, pods=pods, force=args.force,
+                         fsdp=args.fsdp, remat=args.remat,
+                         moe_dispatch=args.moe_dispatch, accum=args.accum,
+                         kv_replicate=args.kv_replicate,
+                         bf16_params=args.bf16_params, bf16_ar=args.bf16_ar,
+                         cp_decode=args.cp_decode)
+    failed = [f"{r['arch']}/{r['shape']}/pod{2 if r['multi_pod'] else 1}"
+              for r in results if "error" in r]
+    if failed:
+        raise SystemExit(f"dry-run failed for {len(failed)} of "
+                         f"{len(results)} cells: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -9,15 +9,25 @@ parallelism over the inter-pod DCN/ICI links.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.logical import AxisRules
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    """A device mesh whose axes are all ``AxisType.Auto``.
+
+    The model code places work through GSPMD: logical-axis rules and
+    ``with_sharding_constraint``, which may only name Auto axes.
+    ``jax.make_mesh`` builds Explicit axes by default, so every mesh in the
+    repo is built here."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def batch_axes(mesh: Mesh):

@@ -17,6 +17,7 @@ from ..models import init_params
 from ..models.config import ModelConfig
 from ..parallel.logical import use_rules
 from ..serve.engine import GenerationResult, ServeEngine
+from .compile_cache import use_compile_cache
 from .mesh import make_axis_rules
 from .train import parse_mesh
 
@@ -52,6 +53,7 @@ def main():
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--mesh")
     args = ap.parse_args()
+    use_compile_cache()
     run_serve(get_config(args.arch, smoke=args.smoke),
               requests=args.requests, prompt_len=args.prompt_len,
               tokens=args.tokens, mesh_spec=args.mesh)

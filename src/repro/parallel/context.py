@@ -16,7 +16,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..kernels.decode_attention.ref import decode_attention_ref
@@ -56,11 +55,11 @@ def decode_attention_cache_layout(mesh: Mesh, q, cache_k, cache_v, kv_len,
     ba = tuple(a for a in ba if a in mesh.axis_names)
     bspec = ba if len(ba) > 1 else (ba[0] if ba else None)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(bspec, None, None),
                        P(bspec, axis, None, None),
                        P(bspec, axis, None, None), P()),
-             out_specs=P(bspec, None, None), check_rep=False)
+             out_specs=P(bspec, None, None), check_vma=False)
     def fn(q_l, k_shard, v_shard, kv_len):
         idx = jax.lax.axis_index(axis)
         s_local = k_shard.shape[1]
@@ -87,10 +86,10 @@ def context_parallel_decode(mesh: Mesh, axis: str = "model",
     """
     n_shards = mesh.shape[axis]
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(), P(None, None, axis, None),
                        P(None, None, axis, None), P()),
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def fn(q, k_shard, v_shard, kv_len):
         idx = jax.lax.axis_index(axis)
         s_local = k_shard.shape[2]

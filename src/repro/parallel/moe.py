@@ -26,7 +26,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -55,8 +54,8 @@ def moe_shard_map(p: dict, x: jax.Array, cfg, mesh: Mesh,
     if has_gate:
         in_specs.insert(3, wspec)
 
-    @partial(shard_map, mesh=mesh, in_specs=tuple(in_specs),
-             out_specs=P(ba, None, None), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=tuple(in_specs),
+             out_specs=P(ba, None, None), check_vma=False)
     def fn(x_l, router, wi, *rest):
         if has_gate:
             wg, wo = rest

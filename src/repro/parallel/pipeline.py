@@ -16,7 +16,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -31,9 +30,9 @@ def pipeline_forward(mesh: Mesh, stage_fn: Callable, n_stages: int,
     as in a transformer trunk.
     """
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis), P()),
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def run(params, xs):
         params = jax.tree.map(lambda a: a[0], params)  # this stage's slice
         sidx = jax.lax.axis_index(axis)

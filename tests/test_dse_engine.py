@@ -198,6 +198,60 @@ def test_engine_rejects_unknown_mp_context():
         DSEEngine(mp_context="teleport")
 
 
+_WORKER_PROBE = '''
+import os
+import sys
+
+BORN = os.environ.get("JAX_PLATFORMS")  # as each process imported __main__
+import jax  # imported first, as a training or serving process has
+
+
+def probe(_):
+    from jax._src import xla_bridge
+
+    pinned = jax.config.jax_platforms
+    if pinned != "cpu":  # never reach for an accelerator unpinned
+        return BORN, os.environ.get("JAX_PLATFORMS"), pinned, None
+    jax.devices()  # what a careless worker would do
+    return (BORN, os.environ.get("JAX_PLATFORMS"), pinned,
+            tuple(sorted(xla_bridge._backends)))
+
+
+if __name__ == "__main__":
+    from repro.core import DSEEngine
+
+    engine = DSEEngine(parallel=True, max_workers=2)
+    assert engine._start_method() == "forkserver", engine._start_method()
+    with engine:
+        seen = set(engine._session_pool.map(probe, range(8), chunksize=1))
+    print(sorted(seen, key=repr))
+    assert seen == {("cpu", "cpu", "cpu", ("cpu",))}, seen
+    assert "JAX_PLATFORMS" not in os.environ  # the parent's is untouched
+'''
+
+
+def test_pool_workers_are_pinned_to_the_cpu(tmp_path):
+    """A pool worker of an engine whose process imported jax (forkserver)
+    is born pinned to the CPU platform: its environment names the CPU
+    before it imports ``__main__`` and jax, and it initializes no JAX
+    backend but the CPU even where its parent's environment names none. A
+    parent that holds the chip relies on it."""
+    import multiprocessing
+    import subprocess
+    import sys
+
+    if "forkserver" not in multiprocessing.get_all_start_methods():
+        pytest.skip("forkserver not available on this platform")
+    script = tmp_path / "probe_workers.py"
+    script.write_text(_WORKER_PROBE)
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    proc = subprocess.run([sys.executable, str(script)], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, f"OUT:\n{proc.stdout}\nERR:\n{proc.stderr}"
+
+
 def test_candidate_matrix_shipping_spawn_exactly_once():
     """Spawn workers ship one PlannedGroup (candidate matrix + winners)
     per (chip, net, topology) system group; the parent's batched

@@ -30,7 +30,7 @@ def test_checkpoint_elastic_across_mesh_shapes(tmp_path):
     from repro.configs import get_config
     from repro.models import init_params, synth_batch
     from repro.parallel.logical import use_rules
-    from repro.launch.mesh import make_axis_rules
+    from repro.launch.mesh import make_axis_rules, make_mesh
     from repro.launch.shardings import (batch_shardings, opt_shardings,
                                         param_shardings)
     from repro.train.checkpoint import CheckpointManager
@@ -43,7 +43,7 @@ def test_checkpoint_elastic_across_mesh_shapes(tmp_path):
     mgr = CheckpointManager({str(ckpt)!r})
 
     def run_on(shape, params, opt, batches):
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         with mesh, use_rules(make_axis_rules(mesh), mesh):
             ps = param_shardings(cfg, mesh, fsdp=True)
             os_ = opt_shardings(cfg, mesh, fsdp=True)

@@ -53,3 +53,24 @@ def test_launch_train_module():
                extra_env={"XLA_FLAGS":
                           "--xla_force_host_platform_device_count=8"})
     assert "done" in out
+
+
+def test_dryrun_main_exits_nonzero_when_a_cell_fails(monkeypatch):
+    """A failed cell is recorded and the sweep goes on, but the CLI's exit
+    status reports it."""
+    import pytest
+
+    from repro.launch import dryrun
+
+    def fail(arch, shape, multi_pod, **_):
+        raise RuntimeError(f"{arch}/{shape} does not compile")
+
+    monkeypatch.setattr(dryrun, "run_cell", fail)
+    # main installs XLA_FLAGS with setdefault; keep it out of this process
+    monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
+    monkeypatch.setattr(sys, "argv", ["dryrun", "--arch", "olmo_1b",
+                                      "--shape", "train_4k"])
+    with pytest.raises(SystemExit) as exc:
+        dryrun.main()
+    assert exc.value.code not in (0, None)
+    assert "olmo_1b/train_4k" in str(exc.value.code)

@@ -33,8 +33,9 @@ def test_pipeline_forward_matches_sequential():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh
     from repro.parallel.pipeline import pipeline_forward
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     n_stages, n_micro, mb, d = 4, 8, 2, 16
     key = jax.random.PRNGKey(0)
     params = jax.random.normal(key, (n_stages, d, d)) * 0.3
@@ -61,8 +62,9 @@ def test_context_parallel_decode_matches_dense():
     import jax, jax.numpy as jnp, numpy as np
     from repro.parallel.context import context_parallel_decode
     from repro.kernels.decode_attention.ref import decode_attention_ref
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     b, h, s, hd = 2, 4, 1024, 64
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(kq, (b, h, hd), jnp.float32)
@@ -89,7 +91,7 @@ def test_sharded_train_step_matches_single_device():
     from repro.configs import get_config
     from repro.models import init_params, loss_fn, synth_batch
     from repro.parallel.logical import use_rules
-    from repro.launch.mesh import make_axis_rules
+    from repro.launch.mesh import make_axis_rules, make_mesh
     from repro.launch.shardings import batch_shardings, param_shardings
     from repro.train.optimizer import AdamWConfig, adamw_init
     from repro.train.trainer import make_train_step
@@ -104,7 +106,7 @@ def test_sharded_train_step_matches_single_device():
     # single device reference
     p_ref, o_ref, m_ref = jax.jit(step)(params, opt, batch)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_axis_rules(mesh)
     with mesh, use_rules(rules):
         ps = param_shardings(cfg, mesh)
@@ -133,8 +135,9 @@ def test_dp_grad_allreduce_emitted():
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch import hlocost
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     w = jnp.zeros((64, 64))
 
     def step(w, x):
@@ -165,12 +168,12 @@ def test_moe_expert_parallel_lowms_to_collectives():
     from repro.configs import get_config
     from repro.models import init_params, loss_fn, synth_batch
     from repro.parallel.logical import use_rules
-    from repro.launch.mesh import make_axis_rules
+    from repro.launch.mesh import make_axis_rules, make_mesh
     from repro.launch.shardings import batch_shardings, param_shardings
     from repro.launch import hlocost
 
     cfg = get_config("olmoe_1b_7b", smoke=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_axis_rules(mesh)
     with mesh, use_rules(rules):
         ps = param_shardings(cfg, mesh)

@@ -40,7 +40,7 @@ def test_moe_shard_map_matches_gspmd_dispatch():
     from repro.configs import get_config
     from repro.models import layers as L
     from repro.parallel.logical import use_rules
-    from repro.launch.mesh import make_axis_rules
+    from repro.launch.mesh import make_axis_rules, make_mesh
 
     cfg = get_config("olmoe_1b_7b", smoke=True)
     p = L.init_moe(jax.random.PRNGKey(1), cfg)
@@ -48,7 +48,7 @@ def test_moe_shard_map_matches_gspmd_dispatch():
                           jnp.float32)
     ref = L.moe(p, x, cfg)                      # no mesh: gspmd path
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg_sm = dataclasses.replace(cfg, moe_dispatch="shard_map")
     rules = make_axis_rules(mesh)
     with mesh, use_rules(rules, mesh):
@@ -64,10 +64,11 @@ def test_fsdp_shards_every_large_param():
     import jax
     from jax.sharding import PartitionSpec as P
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.launch.shardings import param_shardings
 
     cfg = get_config("olmo_1b", smoke=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     base = param_shardings(cfg, mesh, fsdp=False)
     fsdp = param_shardings(cfg, mesh, fsdp=True)
     n_more = 0
@@ -91,7 +92,7 @@ def test_fsdp_train_step_matches_baseline_loss():
     from repro.configs import get_config
     from repro.models import init_params, synth_batch
     from repro.parallel.logical import use_rules
-    from repro.launch.mesh import make_axis_rules
+    from repro.launch.mesh import make_axis_rules, make_mesh
     from repro.launch.shardings import (batch_shardings, opt_shardings,
                                         param_shardings)
     from repro.train.optimizer import AdamWConfig, adamw_init
@@ -104,7 +105,7 @@ def test_fsdp_train_step_matches_baseline_loss():
     step = make_train_step(cfg, AdamWConfig(lr=1e-3))
     _, _, m_ref = jax.jit(step)(params, opt, batch)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with mesh, use_rules(make_axis_rules(mesh), mesh):
         ps = param_shardings(cfg, mesh, fsdp=True)
         os_ = opt_shardings(cfg, mesh, fsdp=True)
@@ -184,7 +185,7 @@ def test_context_parallel_decode_matches_gspmd():
     from repro.configs import get_config
     from repro.models import decode_step, init_cache, init_params
     from repro.parallel.logical import use_rules
-    from repro.launch.mesh import make_axis_rules
+    from repro.launch.mesh import make_axis_rules, make_mesh
 
     cfg = get_config("mistral_nemo_12b", smoke=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -199,7 +200,7 @@ def test_context_parallel_decode_matches_gspmd():
     ref, _ = jax.jit(lambda p, c: decode_step(cfg, p, c, tok, pos))(
         params, cache)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg_cp = dataclasses.replace(cfg, decode_attn="context_parallel")
     with mesh, use_rules(make_axis_rules(mesh), mesh):
         got, _ = jax.jit(lambda p, c: decode_step(cfg_cp, p, c, tok, pos))(
