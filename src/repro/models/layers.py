@@ -252,15 +252,17 @@ def decode_self_attention(p: dict, x: jax.Array, cache_k: jax.Array,
     """
     b, _, d = x.shape
     hd = cfg.hd
-    q = _mm(x, p["wq"], cfg).reshape(b, 1, cfg.n_heads, hd)
-    k = _mm(x, p["wk"], cfg).reshape(b, 1, cfg.n_kv_heads, hd)
-    v = _mm(x, p["wv"], cfg).reshape(b, 1, cfg.n_kv_heads, hd)
-    q = apply_rope(q, pos[None], cfg.rope_theta)
-    k = apply_rope(k, pos[None], cfg.rope_theta)
-    cache_k = jax.lax.dynamic_update_slice_in_dim(
-        cache_k, k.astype(cache_k.dtype), pos, axis=1)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(
-        cache_v, v.astype(cache_v.dtype), pos, axis=1)
+    with jax.named_scope("attention"):
+        q = _mm(x, p["wq"], cfg).reshape(b, 1, cfg.n_heads, hd)
+        k = _mm(x, p["wk"], cfg).reshape(b, 1, cfg.n_kv_heads, hd)
+        v = _mm(x, p["wv"], cfg).reshape(b, 1, cfg.n_kv_heads, hd)
+        q = apply_rope(q, pos[None], cfg.rope_theta)
+        k = apply_rope(k, pos[None], cfg.rope_theta)
+    with jax.named_scope("kv_cache"):
+        cache_k = jax.lax.dynamic_update_slice_in_dim(
+            cache_k, k.astype(cache_k.dtype), pos, axis=1)
+        cache_v = jax.lax.dynamic_update_slice_in_dim(
+            cache_v, v.astype(cache_v.dtype), pos, axis=1)
     if cfg.decode_attn == "context_parallel":
         from ..parallel.logical import current_mesh
         mesh = current_mesh()
@@ -275,19 +277,22 @@ def decode_self_attention(p: dict, x: jax.Array, cache_k: jax.Array,
             return _mm(o, p["wo"], cfg), cache_k, cache_v
     smax = cache_k.shape[1]
     n_rep = cfg.n_heads // cfg.n_kv_heads
-    kk = _repeat_kv(cache_k, n_rep)
-    vv = _repeat_kv(cache_v, n_rep)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                        kk.astype(jnp.float32)) / math.sqrt(hd)
-    mask = jnp.arange(smax)[None, None, None, :] <= pos
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    # keep the PV contraction in f32: downcasting probs to the cache dtype
-    # costs ~3 decimal digits for nothing and makes greedy decode disagree
-    # with the context-parallel path (which reduces in f32) on near-ties
-    o = jnp.einsum("bhqk,bkhd->bqhd", probs, vv.astype(jnp.float32))
-    o = o.astype(x.dtype).reshape(b, 1, cfg.n_heads * hd)
-    return o @ p["wo"].astype(x.dtype), cache_k, cache_v
+    with jax.named_scope("kv_cache"):      # the cache, cast for attention
+        kk = _repeat_kv(cache_k, n_rep).astype(jnp.float32)
+        vv = _repeat_kv(cache_v, n_rep).astype(jnp.float32)
+    with jax.named_scope("attention"):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                            kk) / math.sqrt(hd)
+        mask = jnp.arange(smax)[None, None, None, :] <= pos
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1)
+        # keep the PV contraction in f32: downcasting probs to the cache
+        # dtype costs ~3 decimal digits for nothing and makes greedy decode
+        # disagree with the context-parallel path (which reduces in f32) on
+        # near-ties
+        o = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+        o = o.astype(x.dtype).reshape(b, 1, cfg.n_heads * hd)
+        return o @ p["wo"].astype(x.dtype), cache_k, cache_v
 
 
 # ================================= MLP =======================================

@@ -109,19 +109,20 @@ def _block_fwd(cfg: ModelConfig, bp: dict, x: jax.Array,
     for i in range(cfg.block_size):
         lp = bp[f"l{i}"]
         if cfg.layer_kind(i) == "attn":
-            x = x + L.self_attention(lp["attn"], norm(lp["ln1"], x), cfg,
-                                     positions)
+            with jax.named_scope("attention"):
+                x = x + L.self_attention(lp["attn"], norm(lp["ln1"], x), cfg,
+                                         positions)
             if cfg.layer_is_cross(i) and memory is not None:
                 x = x + L.cross_attention(lp["xattn"], norm(lp["lnx"], x),
                                           memory, cfg)
         else:
             x = x + L.ssm_layer(lp["ssm"], norm(lp["ln1"], x), cfg)
         if cfg.d_ff:
-            h = norm(lp["ln2"], x)
             if cfg.layer_is_moe(i):
-                x = x + L.moe(lp["moe"], h, cfg)
+                x = x + L.moe(lp["moe"], norm(lp["ln2"], x), cfg)
             else:
-                x = x + L.mlp(lp["mlp"], h, cfg)
+                with jax.named_scope("mlp"):
+                    x = x + L.mlp(lp["mlp"], norm(lp["ln2"], x), cfg)
         x = shard(x, "batch", "seq", None)
     return x
 
@@ -174,11 +175,12 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array,
     x = _scan_stack(lambda bp, h: _block_fwd(cfg, bp, h, pos, memory),
                     x, params["stack"], remat=remat, policy=cfg.remat)
     _, norm = L.make_norm(cfg)
-    x = norm(params["final_norm"], x)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(compute_dtype)
-    logits = x @ head
-    return shard(logits, "batch", "seq", "vocab")
+    with jax.named_scope("lm_head"):
+        x = norm(params["final_norm"], x)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(compute_dtype)
+        logits = shard(x @ head, "batch", "seq", "vocab")
+    return logits
 
 
 def loss_fn(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
@@ -251,12 +253,15 @@ def _block_decode(cfg: ModelConfig, bp: dict, bc: dict, x: jax.Array,
         lp = bp[f"l{i}"]
         if cfg.layer_kind(i) == "attn":
             slot = spec.attn_slots[i]
-            h, ck, cv = L.decode_self_attention(
-                lp["attn"], norm(lp["ln1"], x), new_c["k"][slot],
-                new_c["v"][slot], pos, cfg)
-            x = x + h
-            new_c["k"] = new_c["k"].at[slot].set(ck)
-            new_c["v"] = new_c["v"].at[slot].set(cv)
+            with jax.named_scope("kv_cache"):
+                ck, cv = new_c["k"][slot], new_c["v"][slot]
+            with jax.named_scope("attention"):
+                h, ck, cv = L.decode_self_attention(
+                    lp["attn"], norm(lp["ln1"], x), ck, cv, pos, cfg)
+                x = x + h
+            with jax.named_scope("kv_cache"):
+                new_c["k"] = new_c["k"].at[slot].set(ck)
+                new_c["v"] = new_c["v"].at[slot].set(cv)
             if cfg.layer_is_cross(i) and memory is not None:
                 x = x + L.cross_attention(lp["xattn"], norm(lp["lnx"], x),
                                           memory, cfg)
@@ -269,11 +274,11 @@ def _block_decode(cfg: ModelConfig, bp: dict, bc: dict, x: jax.Array,
             new_c["ssm"] = new_c["ssm"].at[slot].set(st)
             new_c["conv"] = new_c["conv"].at[slot].set(cc)
         if cfg.d_ff:
-            hh = norm(lp["ln2"], x)
-            if cfg.layer_is_moe(i):
-                x = x + L.moe_dense(lp["moe"], hh, cfg)  # dropless at T=1
+            if cfg.layer_is_moe(i):    # dropless at T=1
+                x = x + L.moe_dense(lp["moe"], norm(lp["ln2"], x), cfg)
             else:
-                x = x + L.mlp(lp["mlp"], hh, cfg)
+                with jax.named_scope("mlp"):
+                    x = x + L.mlp(lp["mlp"], norm(lp["ln2"], x), cfg)
     return x, new_c
 
 
@@ -293,13 +298,18 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
         y, nc = _block_decode(cfg, bp, bc, carry, pos, memory)
         return y, nc
 
-    x, new_cache = jax.lax.scan(step, x, (params["stack"], cache))
+    # the scan's own ops (each layer's weights and cache sliced from the
+    # stack, the new cache stacked back) are named layers, apart from the
+    # per-layer cache ops inside that _block_decode names kv_cache
+    with jax.named_scope("layers"):
+        x, new_cache = jax.lax.scan(step, x, (params["stack"], cache))
     _, norm = L.make_norm(cfg)
-    x = norm(params["final_norm"], x)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(compute_dtype)
-    logits = (x[:, 0, :] @ head)
-    return shard(logits, "batch", "vocab"), new_cache
+    with jax.named_scope("lm_head"):
+        x = norm(params["final_norm"], x)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(compute_dtype)
+        logits = shard(x[:, 0, :] @ head, "batch", "vocab")
+    return logits, new_cache
 
 
 def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array,
@@ -311,8 +321,9 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array,
     correct w.r.t. decode_step (tested).
     """
     logits = forward(cfg, params, tokens, memory=memory)
-    cache = init_cache(cfg, tokens.shape[0], tokens.shape[1])
-    cache = _fill_cache(cfg, params, tokens, cache, memory)
+    with jax.named_scope("fill_cache"):
+        cache = init_cache(cfg, tokens.shape[0], tokens.shape[1])
+        cache = _fill_cache(cfg, params, tokens, cache, memory)
     return logits, cache
 
 

@@ -16,6 +16,7 @@ Two decode drivers share the jitted step:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 
 import jax
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 
 from ..models import decode_step, init_cache, prefill
 from ..models.config import ModelConfig
+from ..tracing import count, span
 
 
 @dataclasses.dataclass
@@ -68,6 +70,7 @@ class ServeEngine:
                                                   memory=mem))
         self._prefill = jax.jit(
             lambda p, t, mem: prefill(cfg, p, t, memory=mem))
+        self._calls = itertools.count()
 
     # --- shared plumbing ----------------------------------------------------
     def _check_window(self, s: int, n_tokens: int) -> None:
@@ -114,31 +117,54 @@ class ServeEngine:
                  memory: jax.Array | None = None,
                  temperature: float = 0.0,
                  rng: jax.Array | None = None) -> GenerationResult:
-        """prompts: (B, S) int32 (same length; pad upstream)."""
+        """prompts: (B, S) int32 (same length; pad upstream).
+
+        Under a profiler session (``repro.tracing``) each call records the
+        spans ``serve.generate`` > ``serve.prefill``, ``serve.rehome``,
+        ``serve.sample``, ``serve.wait``, ``serve.decode_step`` x
+        (n_tokens - 1), ``serve.wait``, ``serve.to_host``, all with one
+        engine-local call id, and the counter ``serve.exposed_s``: host
+        seconds from each sync's return to the return of the engine's next
+        dispatch (after the last sync, to the call's return), in which the
+        device holds none of its work.
+        """
         b, s = prompts.shape
         self._check_window(s, n_tokens)
-        t0 = time.perf_counter()
-        logits, cache0 = self._prefill(self.params, prompts, memory)
-        cache = self._rehome(cache0, b, s)
-        rng, sub = self._next_key(rng)
-        next_tok = self._sample(logits[:, -1], temperature, sub)
-        jax.block_until_ready(next_tok)
-        ttft = time.perf_counter() - t0
+        with span("serve.generate", call=next(self._calls)):
+            t0 = time.perf_counter()
+            with span("serve.prefill"):
+                logits, cache0 = self._prefill(self.params, prompts, memory)
+            with span("serve.rehome"):
+                cache = self._rehome(cache0, b, s)
+            with span("serve.sample"):
+                rng, sub = self._next_key(rng)
+                next_tok = self._sample(logits[:, -1], temperature, sub)
+            with span("serve.wait"):
+                jax.block_until_ready(next_tok)
+            synced = time.perf_counter()
+            ttft = synced - t0
 
-        toks = [next_tok]
-        t1 = time.perf_counter()
-        pos = s
-        for _ in range(n_tokens - 1):
-            logits_i, cache = self._decode(self.params, cache, toks[-1],
-                                           jnp.int32(pos), memory)
-            rng, sub = self._next_key(rng)
-            toks.append(self._sample(logits_i, temperature, sub))
-            pos += 1
-        jax.block_until_ready(toks[-1])
-        dt = time.perf_counter() - t1
+            toks = [next_tok]
+            for pos in range(s, s + n_tokens - 1):
+                with span("serve.decode_step"):
+                    logits_i, cache = self._decode(self.params, cache,
+                                                   toks[-1], jnp.int32(pos),
+                                                   memory)
+                    if pos == s:      # the first dispatch after the first sync
+                        count("serve.exposed_s", time.perf_counter() - synced)
+                    rng, sub = self._next_key(rng)
+                    toks.append(self._sample(logits_i, temperature, sub))
+            with span("serve.wait"):
+                jax.block_until_ready(toks[-1])
+            end = time.perf_counter()
+            dt = end - synced
+            with span("serve.to_host"):
+                tokens = [t.tolist() for t in toks]
+            last_sync = end if n_tokens > 1 else synced
+            count("serve.exposed_s", time.perf_counter() - last_sync)
         tpot = dt / max(n_tokens - 1, 1)
         return GenerationResult(
-            tokens=[t.tolist() for t in toks], ttft=ttft, tpot=tpot,
+            tokens=tokens, ttft=ttft, tpot=tpot,
             tokens_per_s=b * n_tokens / (ttft + dt))
 
     # --- measurement path ---------------------------------------------------
