@@ -241,10 +241,35 @@ def cross_attention(p: dict, x: jax.Array, memory: jax.Array,
     return shard(_mm(o, p["wo"], cfg), "batch", "seq", None)
 
 
+def _at(buf: jax.Array, at: tuple) -> jax.Array:
+    """The entry of ``buf`` at the leading indices ``at`` (ints or traced
+    scalars): a dynamic slice, so a consumer can read it in place."""
+    starts = (*at,) + (0,) * (buf.ndim - len(at))
+    sizes = (1,) * len(at) + buf.shape[len(at):]
+    return jax.lax.dynamic_slice(buf, starts, sizes).reshape(
+        buf.shape[len(at):])
+
+
+def _write_at(buf: jax.Array, at: tuple, new: jax.Array,
+              offset: tuple = ()) -> jax.Array:
+    """``buf`` with ``new`` written into its entry at ``at``, starting at
+    ``offset`` within the entry: one ``dynamic_update_slice``."""
+    starts = (*at, *offset) + (0,) * (new.ndim - len(offset))
+    return jax.lax.dynamic_update_slice(
+        buf, new.astype(buf.dtype).reshape((1,) * len(at) + new.shape),
+        starts)
+
+
 def decode_self_attention(p: dict, x: jax.Array, cache_k: jax.Array,
-                          cache_v: jax.Array, pos: jax.Array,
+                          cache_v: jax.Array, at: tuple, pos: jax.Array,
                           cfg: ModelConfig):
-    """One-token decode. x: (B,1,d); cache_{k,v}: (B,Smax,Hkv,hd); pos scalar.
+    """One-token decode. x: (B,1,d); pos scalar; cache_{k,v}: the stacked
+    buffers whose entry at the leading indices ``at`` is this layer's
+    (B,Smax,Hkv,hd) cache.
+
+    The new K/V row is written into the buffers (one dynamic_update_slice
+    each, in place when the caller carries them) and attention reads the
+    layer's entry of the updated buffers. Returns (out, cache_k, cache_v).
 
     Under the production mesh the cache sequence dim is sharded on 'model'
     (context parallelism): GSPMD turns the softmax/O reductions into
@@ -259,27 +284,26 @@ def decode_self_attention(p: dict, x: jax.Array, cache_k: jax.Array,
         q = apply_rope(q, pos[None], cfg.rope_theta)
         k = apply_rope(k, pos[None], cfg.rope_theta)
     with jax.named_scope("kv_cache"):
-        cache_k = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k.astype(cache_k.dtype), pos, axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v.astype(cache_v.dtype), pos, axis=1)
+        cache_k = _write_at(cache_k, at, k, (0, pos))
+        cache_v = _write_at(cache_v, at, v, (0, pos))
+        ck, cv = _at(cache_k, at), _at(cache_v, at)
     if cfg.decode_attn == "context_parallel":
         from ..parallel.logical import current_mesh
         mesh = current_mesh()
         if (mesh is not None and "model" in mesh.axis_names
-                and cache_k.shape[1] % mesh.shape["model"] == 0):
+                and ck.shape[1] % mesh.shape["model"] == 0):
             from ..parallel.context import decode_attention_cache_layout
             ba = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
             o = decode_attention_cache_layout(
                 mesh, q[:, 0].astype(jnp.float32),
-                cache_k, cache_v, pos + 1, ba)
+                ck, cv, pos + 1, ba)
             o = o.astype(x.dtype).reshape(b, 1, cfg.n_heads * hd)
             return _mm(o, p["wo"], cfg), cache_k, cache_v
-    smax = cache_k.shape[1]
+    smax = ck.shape[1]
     n_rep = cfg.n_heads // cfg.n_kv_heads
     with jax.named_scope("kv_cache"):      # the cache, cast for attention
-        kk = _repeat_kv(cache_k, n_rep).astype(jnp.float32)
-        vv = _repeat_kv(cache_v, n_rep).astype(jnp.float32)
+        kk = _repeat_kv(ck, n_rep).astype(jnp.float32)
+        vv = _repeat_kv(cv, n_rep).astype(jnp.float32)
     with jax.named_scope("attention"):
         logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                             kk) / math.sqrt(hd)

@@ -244,42 +244,39 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return cache
 
 
-def _block_decode(cfg: ModelConfig, bp: dict, bc: dict, x: jax.Array,
-                  pos: jax.Array, memory: jax.Array | None):
+def _block_decode(cfg: ModelConfig, bp: dict, cache: dict, blk: jax.Array,
+                  x: jax.Array, pos: jax.Array, memory: jax.Array | None):
+    """One block of a decode step on the whole stacked ``cache``: each
+    layer writes its new state into its ``[blk, slot]`` entry in place."""
     _, norm = L.make_norm(cfg)
     spec = cache_spec(cfg)
-    new_c = {k: v for k, v in bc.items()}
+    cache = dict(cache)
     for i in range(cfg.block_size):
         lp = bp[f"l{i}"]
         if cfg.layer_kind(i) == "attn":
-            slot = spec.attn_slots[i]
-            with jax.named_scope("kv_cache"):
-                ck, cv = new_c["k"][slot], new_c["v"][slot]
             with jax.named_scope("attention"):
-                h, ck, cv = L.decode_self_attention(
-                    lp["attn"], norm(lp["ln1"], x), ck, cv, pos, cfg)
+                h, cache["k"], cache["v"] = L.decode_self_attention(
+                    lp["attn"], norm(lp["ln1"], x), cache["k"], cache["v"],
+                    (blk, spec.attn_slots[i]), pos, cfg)
                 x = x + h
-            with jax.named_scope("kv_cache"):
-                new_c["k"] = new_c["k"].at[slot].set(ck)
-                new_c["v"] = new_c["v"].at[slot].set(cv)
             if cfg.layer_is_cross(i) and memory is not None:
                 x = x + L.cross_attention(lp["xattn"], norm(lp["lnx"], x),
                                           memory, cfg)
         else:
-            slot = spec.ssm_slots[i]
+            at = (blk, spec.ssm_slots[i])
             h, st, cc = L.ssm_decode_step(
-                lp["ssm"], norm(lp["ln1"], x), new_c["ssm"][slot],
-                new_c["conv"][slot], cfg)
+                lp["ssm"], norm(lp["ln1"], x), L._at(cache["ssm"], at),
+                L._at(cache["conv"], at), cfg)
             x = x + h
-            new_c["ssm"] = new_c["ssm"].at[slot].set(st)
-            new_c["conv"] = new_c["conv"].at[slot].set(cc)
+            cache["ssm"] = L._write_at(cache["ssm"], at, st)
+            cache["conv"] = L._write_at(cache["conv"], at, cc)
         if cfg.d_ff:
             if cfg.layer_is_moe(i):    # dropless at T=1
                 x = x + L.moe_dense(lp["moe"], norm(lp["ln2"], x), cfg)
             else:
                 with jax.named_scope("mlp"):
                     x = x + L.mlp(lp["mlp"], norm(lp["ln2"], x), cfg)
-    return x, new_c
+    return x, cache
 
 
 def decode_step(cfg: ModelConfig, params: dict, cache: dict,
@@ -287,22 +284,27 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
                 memory: jax.Array | None = None):
     """One autoregressive step. token: (B,) int32; pos: scalar int32.
 
+    The layer scan carries the whole cache: each layer writes its new K/V
+    row (or SSM state) into it in place and attention reads its entry
+    there, so nothing copies a layer's cache out or stacks one back. A
+    caller that donates ``cache`` gets the update without a copy.
+
     Returns (logits (B, V), updated cache)."""
     compute_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     x = params["embed"][token][:, None, :].astype(compute_dtype)  # (B,1,d)
     if memory is not None:
         memory = memory.astype(compute_dtype)
 
-    def step(carry, inp):
-        bp, bc = inp
-        y, nc = _block_decode(cfg, bp, bc, carry, pos, memory)
-        return y, nc
+    def step(carry, bp):
+        y, c, blk = carry
+        y, c = _block_decode(cfg, bp, c, blk, y, pos, memory)
+        return (y, c, blk + 1), None
 
-    # the scan's own ops (each layer's weights and cache sliced from the
-    # stack, the new cache stacked back) are named layers, apart from the
-    # per-layer cache ops inside that _block_decode names kv_cache
+    # the scan's own ops (each layer's weights sliced from the stack) are
+    # named layers; the cache row writes and reads inside are kv_cache
     with jax.named_scope("layers"):
-        x, new_cache = jax.lax.scan(step, x, (params["stack"], cache))
+        (x, new_cache, _), _ = jax.lax.scan(
+            step, (x, cache, jnp.int32(0)), params["stack"])
     _, norm = L.make_norm(cfg)
     with jax.named_scope("lm_head"):
         x = norm(params["final_norm"], x)

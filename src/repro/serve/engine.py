@@ -3,7 +3,11 @@
 temperature sampling). This is the executable twin of the paper's §VIII.A
 serving model — TTFT = prefill latency, TPOT = decode step latency.
 
-Two decode drivers share the jitted step:
+Decode runs one compiled step per batch and memory shape, compiled on first
+use (:func:`compile_decode`): it donates the cache and keeps it in the
+layout the compiler picks, so each step writes its new K/V rows into the
+cache it was given and hands that cache on to the next. Two decode drivers
+share it:
 
 * :meth:`ServeEngine.generate` — the serving path: one sync at the end of
   the decode loop, so XLA pipelines step dispatch (throughput-faithful
@@ -18,9 +22,11 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 from ..models import decode_step, init_cache, prefill
 from ..models.config import ModelConfig
@@ -56,6 +62,69 @@ class SteadyTiming:
         return self.batch / t if t > 0 else 0.0
 
 
+class DecodeStep(NamedTuple):
+    """The decode step compiled for one batch and memory shape.
+
+    The engine holds each cache entry with its axes in the order, major
+    to minor, in which the compiler chose to keep it for the step
+    (``Layout.AUTO``), so the step, compiled on that cache with default
+    layouts, updates it in place without a relayout copy. ``enter`` moves
+    a cache in ``init_cache``'s order there (one copy); ``run(params,
+    cache, token, pos, memory) -> (logits, cache)`` donates the cache it
+    is given and returns it updated, in the same order."""
+
+    enter: jax.stages.Compiled
+    run: jax.stages.Compiled
+
+
+def _permute(cache: dict, axes: dict) -> dict:
+    return {k: jnp.transpose(v, axes[k]) for k, v in cache.items()}
+
+
+def compile_decode(cfg: ModelConfig, params, cache, token, pos,
+                   memory=None) -> DecodeStep:
+    """``decode_step`` compiled for the shapes of its arguments (arrays or
+    ``jax.ShapeDtypeStruct``) on a donated cache kept in the layout the
+    compiler picks (see :class:`DecodeStep`).
+
+    The layout is read from a compile with ``Layout.AUTO`` for the cache;
+    the step itself is compiled with default layouts on the cache's axes
+    reordered to match, because an executable with a non-default entry
+    layout comes back from JAX's persistent compilation cache with its
+    outputs labelled in the default layout."""
+    def step(p, c, t, i, m):
+        return decode_step(cfg, p, c, t, i, memory=m)
+
+    cache = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding), cache)
+    auto = Format(Layout.AUTO)
+    picked = jax.jit(
+        step, in_shardings=(None, auto, None, None, None),
+        out_shardings=(None, auto),
+    ).lower(params, cache, token, pos, memory).compile().input_formats[0][1]
+    order = {k: f.layout.major_to_minor for k, f in picked.items()}
+    back = {k: tuple(o.index(i) for i in range(len(o)))
+            for k, o in order.items()}
+
+    def run(p, c, t, i, m):
+        logits, c = step(p, _permute(c, back), t, i, m)
+        return logits, _permute(c, order)
+
+    enter = jax.jit(lambda c: _permute(c, order)).lower(cache).compile()
+    held = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding), enter.out_info)
+    return DecodeStep(enter, jax.jit(run, donate_argnums=1).lower(
+        params, held, token, pos, memory).compile())
+
+
+def aliased_share(step: DecodeStep, cache) -> float:
+    """The share of ``cache``'s bytes that the compiled decode ``step``
+    writes back into its argument's buffers (1.0: every byte)."""
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    return step.run.memory_analysis().alias_size_in_bytes / cache_bytes
+
+
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, max_batch: int = 8,
                  max_len: int = 1024):
@@ -63,13 +132,11 @@ class ServeEngine:
         self.params = params
         self.max_batch = max_batch
         self.max_len = max_len
-        # both jitted paths close over cfg and thread the cross-attention
+        # both compiled paths close over cfg and thread the cross-attention
         # memory operand — tests assert a memory change reaches the logits
-        self._decode = jax.jit(
-            lambda p, c, t, pos, mem: decode_step(cfg, p, c, t, pos,
-                                                  memory=mem))
         self._prefill = jax.jit(
             lambda p, t, mem: prefill(cfg, p, t, memory=mem))
+        self._steps: dict = {}     # (batch, memory shape) -> (step, share)
         self._calls = itertools.count()
 
     # --- shared plumbing ----------------------------------------------------
@@ -94,6 +161,18 @@ class ServeEngine:
             cache["ssm"] = cache0["ssm"]
             cache["conv"] = cache0["conv"]
         return cache
+
+    def _decoder(self, cache: dict, tok: jax.Array, memory):
+        """The compiled decode step for this batch and memory shape (see
+        :func:`compile_decode`, compiled on first use), its share of
+        aliased cache bytes, and ``cache`` moved into the step's order."""
+        key = (tok.shape, None if memory is None else memory.shape)
+        if key not in self._steps:
+            step = compile_decode(self.cfg, self.params, cache, tok,
+                                  jnp.int32(0), memory)
+            self._steps[key] = step, aliased_share(step, cache)
+        step, share = self._steps[key]
+        return step.run, share, step.enter(cache)
 
     @staticmethod
     def _sample(logits: jax.Array, temperature: float,
@@ -147,9 +226,12 @@ class ServeEngine:
             toks = [next_tok]
             for pos in range(s, s + n_tokens - 1):
                 with span("serve.decode_step"):
-                    logits_i, cache = self._decode(self.params, cache,
-                                                   toks[-1], jnp.int32(pos),
-                                                   memory)
+                    if pos == s:
+                        step, share, cache = self._decoder(cache, toks[-1],
+                                                           memory)
+                        count("serve.cache_aliased", share)
+                    logits_i, cache = step(self.params, cache, toks[-1],
+                                           jnp.int32(pos), memory)
                     if pos == s:      # the first dispatch after the first sync
                         count("serve.exposed_s", time.perf_counter() - synced)
                     rng, sub = self._next_key(rng)
@@ -173,9 +255,10 @@ class ServeEngine:
                       memory: jax.Array | None = None) -> SteadyTiming:
         """Steady-state greedy decode with per-step timing.
 
-        Runs prefill, then ``warmup`` decode steps whose times are discarded
-        (the first step pays compilation, the next ones cache/allocator
-        warmup), then ``n_steps`` steps each synced and timed individually.
+        Runs prefill, compiles the decode step on first use and moves the
+        cache into its format, then ``warmup`` decode steps whose times are
+        discarded (cache/allocator warmup), then ``n_steps`` steps each
+        synced and timed individually.
         The decode step's cost is ``max_len``-shaped (slot attention runs
         over the whole cache regardless of position), so every steady step
         does identical work — the per-step spread is measurement noise, not
@@ -191,10 +274,11 @@ class ServeEngine:
         jax.block_until_ready(tok)
         ttft = time.perf_counter() - t0
 
+        step, _, cache = self._decoder(cache, tok, memory)
         pos = s
         for _ in range(warmup):
-            logits_i, cache = self._decode(self.params, cache, tok,
-                                           jnp.int32(pos), memory)
+            logits_i, cache = step(self.params, cache, tok, jnp.int32(pos),
+                                   memory)
             tok = self._sample(logits_i, 0.0, None)
             pos += 1
         jax.block_until_ready(tok)
@@ -202,8 +286,8 @@ class ServeEngine:
         times: list[float] = []
         for _ in range(n_steps):
             t1 = time.perf_counter()
-            logits_i, cache = self._decode(self.params, cache, tok,
-                                           jnp.int32(pos), memory)
+            logits_i, cache = step(self.params, cache, tok, jnp.int32(pos),
+                                   memory)
             tok = self._sample(logits_i, 0.0, None)
             jax.block_until_ready(tok)
             times.append(time.perf_counter() - t1)

@@ -10,12 +10,14 @@ import pytest
 from repro.configs import get_config
 from repro.core.serving import (expected_accepted, serving_sweep,
                                 speculative_throughput)
-from repro.models import init_params
+from repro.models import decode_step, init_params, synth_batch
+from repro.models.transformer import _memory_from_batch
 from repro.serve.engine import ServeEngine
 from repro.systems.chips import HBM_V5E, ICI, SN40L
 from repro.systems.system import SystemSpec
 from repro.systems.topology import torus2d
 from repro.workloads.llm import LLAMA3_8B, decode_layer_graph, gpt_layer_graph
+from test_models_smoke import DECODE_ARCHS
 
 KEY = jax.random.PRNGKey(0)
 
@@ -60,6 +62,79 @@ def test_engine_ssm_generates():
     prompts = jax.random.randint(KEY, (1, 8), 0, cfg.vocab)
     res = eng.generate(prompts, n_tokens=4)
     assert len(res.tokens) == 4
+
+
+@pytest.mark.parametrize("arch", DECODE_ARCHS)
+def test_generate_matches_undonated_decode_steps(arch):
+    """The engine's donated decode step, in the layout its compiler picks,
+    serves exactly the greedy tokens of a plain loop of undonated
+    ``decode_step`` calls in the default layout."""
+    cfg = get_config(arch, smoke=True)
+    params = init_params(cfg, KEY)
+    b, s, n = 2, 8, 5
+    batch = synth_batch(cfg, batch=b, seq=s, seed=1)
+    memory = _memory_from_batch(cfg, params, batch)
+    eng = ServeEngine(cfg, params, max_batch=b, max_len=s + n)
+    got = eng.generate(batch["tokens"], n_tokens=n, memory=memory).tokens
+
+    logits, cache0 = eng._prefill(params, batch["tokens"], memory)
+    cache = eng._rehome(cache0, b, s)
+    plain = jax.jit(lambda p, c, t, i, m: decode_step(cfg, p, c, t, i,
+                                                       memory=m))
+    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+    want = [tok.tolist()]
+    for pos in range(s, s + n - 1):
+        logits_i, cache = plain(params, cache, tok, jnp.int32(pos), memory)
+        tok = jnp.argmax(logits_i, -1).astype(jnp.int32)
+        want.append(tok.tolist())
+    assert got == want
+
+
+def _decoding_engine():
+    cfg = get_config("olmo_1b", smoke=True)
+    params = init_params(cfg, KEY)
+    eng = ServeEngine(cfg, params, max_batch=2, max_len=16)
+    prompts = jax.random.randint(KEY, (2, 8), 0, cfg.vocab)
+    logits, cache0 = eng._prefill(params, prompts, None)
+    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+    return eng, eng._rehome(cache0, 2, 8), tok
+
+
+def test_compiled_decode_step_aliases_every_cache_byte():
+    eng, cache, tok = _decoding_engine()
+    step, share, cache = eng._decoder(cache, tok, None)
+    cache_bytes = sum(x.nbytes for x in jax.tree.leaves(cache))
+    assert step.memory_analysis().alias_size_in_bytes == cache_bytes
+    assert share == 1.0
+
+
+def test_decode_step_consumes_the_callers_cache():
+    eng, rehomed, tok = _decoding_engine()
+    step, _, cache = eng._decoder(rehomed, tok, None)
+    _, out = step(eng.params, cache, tok, jnp.int32(8), None)
+    assert all(x.is_deleted() for x in jax.tree.leaves(cache))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(out))
+    # the updated cache chains into the next step as it is
+    _, out2 = step(eng.params, out, tok, jnp.int32(9), None)
+    assert all(x.is_deleted() for x in jax.tree.leaves(out))
+    assert out2["k"].shape == cache["k"].shape
+
+
+@pytest.mark.parametrize("arch", ["olmo_1b", "mamba2_130m"])
+def test_decode_steady_runs_through_the_donated_step(arch):
+    """decode_steady drives the same compiled, donated step as generate,
+    call after call, and its steady steps time the same work."""
+    cfg = get_config(arch, smoke=True)
+    params = init_params(cfg, KEY)
+    eng = ServeEngine(cfg, params, max_batch=2, max_len=32)
+    prompts = jax.random.randint(KEY, (2, 8), 0, cfg.vocab)
+    for _ in range(2):
+        t = eng.decode_steady(prompts, n_steps=3, warmup=1)
+        assert len(t.step_times) == 3 and all(x > 0 for x in t.step_times)
+    eng.generate(prompts, n_tokens=3)
+    assert len(eng._steps) == 1
+    [(_, share)] = eng._steps.values()
+    assert share == 1.0
 
 
 # ------------------------------ analytical §VIII.A ----------------------------
@@ -177,11 +252,12 @@ def test_memory_threads_both_jitted_paths():
     pre1, cache1 = eng._prefill(params, prompts, m1)
     pre2, _ = eng._prefill(params, prompts, m2)
     assert not jnp.allclose(pre1, pre2)
-    cache = eng._rehome(cache1, 1, 4)
     tok = jnp.argmax(pre1[:, -1], -1).astype(jnp.int32)
-    dec1, _ = eng._decode(params, cache, tok, jnp.int32(4), m1)
-    dec2, _ = eng._decode(params, cache, tok, jnp.int32(4), m2)
-    assert not jnp.allclose(dec1, dec2)
+    decoded = []
+    for m in (m1, m2):      # the step donates its cache: one cache each
+        step, _, cache = eng._decoder(eng._rehome(cache1, 1, 4), tok, m)
+        decoded.append(step(params, cache, tok, jnp.int32(4), m)[0])
+    assert not jnp.allclose(*decoded)
     # and the end-to-end driver accepts it
     res = eng.generate(prompts, n_tokens=3, memory=m1)
     assert len(res.tokens) == 3

@@ -155,6 +155,25 @@ def test_generate_records_its_span_tree(tmp_path):
     assert snap["dropped"] == 0
 
 
+def test_generate_counts_the_aliased_cache_share(tmp_path):
+    """``serve.cache_aliased`` adds 1.0 (every cache byte updated in place)
+    once per ``generate`` call that decodes, and nothing for a call that
+    ends with its first token."""
+    cfg, eng = _engine()
+    prompts = jax.random.randint(KEY, (2, 8), 0, cfg.vocab)
+    eng.generate(prompts, n_tokens=3)                # compiles, not recorded
+    tracing.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        eng.generate(prompts, n_tokens=1)
+        prefill_only = tracing.snapshot()["counters"]
+        for _ in range(2):
+            eng.generate(prompts, n_tokens=3)
+        counters = tracing.snapshot()["counters"]
+    tracing.reset()
+    assert "serve.cache_aliased" not in prefill_only
+    assert counters["serve.cache_aliased"] == 2.0
+
+
 SCOPES = ("layers", "attention", "kv_cache", "mlp", "lm_head", "fill_cache")
 
 
