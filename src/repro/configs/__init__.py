@@ -22,6 +22,7 @@ ARCH_IDS = [
     "jamba_v01_52b",
     "seamless_m4t_medium",
     "mamba2_130m",
+    "granite_4_h_small",
     # paper's own workloads, runnable through the same stack
     "gpt3_175b",
 ]

@@ -31,6 +31,10 @@ class ModelConfig:
     moe_every: int = 1           # a layer is MoE iff (idx % moe_every == moe_offset)
     moe_offset: int = 0
     moe_capacity_factor: float = 1.25
+    moe_shared_ff: int = 0       # >0: a shared SwiGLU expert this wide
+    moe_held: tuple | None = None  # (first, count): the experts this chip
+                                 # holds of moe_experts (expert parallelism);
+                                 # None: all of them
 
     # hybrid / SSM (Mamba2/SSD)
     attn_every: int = 0          # 0: all layers attend; k>0: 1 attn per k layers
@@ -38,6 +42,7 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_conv: int = 4
+    ssm_conv_bias: bool = False
 
     # VLM cross-attention
     cross_attn_every: int = 0    # k>0: layers with idx % k == k-1 cross-attend
@@ -51,6 +56,14 @@ class ModelConfig:
     gated: bool = True           # SwiGLU vs plain GELU MLP
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    pos_emb: str = "rope"        # rope | nope (no position embedding)
+    attn_scale: float | None = None  # softmax scale; None: 1/sqrt(hd)
+    rms_eps: float = 1e-6
+    # muP multipliers (Granite): embeddings times embed_mult, each residual
+    # branch times residual_mult, logits divided by logits_div
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    logits_div: float = 1.0
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
 
@@ -71,6 +84,11 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> tuple[int, int]:
+        """(first, count) of the experts whose weights this chip holds."""
+        return self.moe_held or (0, self.moe_experts)
 
     @property
     def is_enc_dec(self) -> bool:
@@ -139,8 +157,13 @@ class ModelConfig:
             if self.d_ff:
                 n_mats = 3 if self.gated else 2
                 if self.layer_is_moe(i):
-                    e = self.moe_top_k if active_only else self.moe_experts
+                    # the experts this chip holds; of them, a token uses
+                    # k·held/E on average (k where it holds every expert)
+                    held = self.experts_held[1]
+                    e = (self.moe_top_k * held / self.moe_experts
+                         if active_only else held)
                     total += e * n_mats * d * self.d_ff + d * self.moe_experts
+                    total += 3 * d * self.moe_shared_ff
                 else:
                     total += n_mats * d * self.d_ff
         total += self.vocab * d * (1 if self.tie_embeddings else 2)

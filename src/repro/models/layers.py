@@ -79,7 +79,7 @@ def make_norm(cfg: ModelConfig):
                                 "b": jnp.zeros((d,), jnp.float32)},
                 lambda p, x: layernorm(x, p["w"], p["b"]))
     return (lambda key, d: {"w": jnp.ones((d,), jnp.float32)},
-            lambda p, x: rmsnorm(x, p["w"]))
+            lambda p, x: rmsnorm(x, p["w"], cfg.rms_eps))
 
 
 # ================================ RoPE =======================================
@@ -110,13 +110,15 @@ def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
 
 
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                  causal: bool = True, q_offset: int = 0) -> jax.Array:
-    """Dense reference attention. q: (B,Sq,H,hd); k,v: (B,Sk,Hkv,hd)."""
+                  causal: bool = True, q_offset: int = 0,
+                  scale: float | None = None) -> jax.Array:
+    """Dense reference attention. q: (B,Sq,H,hd); k,v: (B,Sk,Hkv,hd);
+    softmax scale ``scale`` (None: 1/sqrt(hd))."""
     b, sq, h, hd = q.shape
     n_rep = h // k.shape[2]
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     if causal:
@@ -130,7 +132,8 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
                       causal: bool = True, block_q: int = 1024,
-                      block_k: int = 1024) -> jax.Array:
+                      block_k: int = 1024,
+                      scale: float | None = None) -> jax.Array:
     """Memory-efficient online-softmax attention (FlashAttention schedule in
     pure jnp — the structural twin of kernels/flash_attention). O(S) memory.
 
@@ -141,7 +144,7 @@ def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
     n_rep = h // k.shape[2]
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     nq = sq // block_q
     nk = sk // block_k
     qb = q.reshape(b, nq, block_q, h, hd)
@@ -186,14 +189,14 @@ def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def attention(q, k, v, causal=True, q_offset: int = 0,
-              chunked_threshold: int = 8192):
+              chunked_threshold: int = 8192, scale: float | None = None):
     """Dispatch dense vs chunked by sequence length."""
     sk = k.shape[1]
     sq = q.shape[1]
     if sq * sk > chunked_threshold * chunked_threshold // 16 and sq > 1 \
             and sq % 1024 == 0 and sk % 1024 == 0 and q_offset == 0:
-        return attention_chunked(q, k, v, causal)
-    return attention_ref(q, k, v, causal, q_offset)
+        return attention_chunked(q, k, v, causal, scale=scale)
+    return attention_ref(q, k, v, causal, q_offset, scale=scale)
 
 
 # ============================ GQA attention layer ============================
@@ -215,12 +218,13 @@ def self_attention(p: dict, x: jax.Array, cfg: ModelConfig,
     q = _mm(x, p["wq"], cfg).reshape(b, s, cfg.n_heads, hd)
     k = _mm(x, p["wk"], cfg).reshape(b, s, cfg.n_kv_heads, hd)
     v = _mm(x, p["wv"], cfg).reshape(b, s, cfg.n_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.pos_emb == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     q = shard(q, "batch", "seq", "heads", None)
     k = shard(k, "batch", "seq", "kv_heads", None)
     v = shard(v, "batch", "seq", "kv_heads", None)
-    o = attention(q, k, v, causal=causal)
+    o = attention(q, k, v, causal=causal, scale=cfg.attn_scale)
     o = o.reshape(b, s, cfg.n_heads * hd)
     return shard(_mm(o, p["wo"], cfg), "batch", "seq", None)
 
@@ -281,8 +285,9 @@ def decode_self_attention(p: dict, x: jax.Array, cache_k: jax.Array,
         q = _mm(x, p["wq"], cfg).reshape(b, 1, cfg.n_heads, hd)
         k = _mm(x, p["wk"], cfg).reshape(b, 1, cfg.n_kv_heads, hd)
         v = _mm(x, p["wv"], cfg).reshape(b, 1, cfg.n_kv_heads, hd)
-        q = apply_rope(q, pos[None], cfg.rope_theta)
-        k = apply_rope(k, pos[None], cfg.rope_theta)
+        if cfg.pos_emb == "rope":
+            q = apply_rope(q, pos[None], cfg.rope_theta)
+            k = apply_rope(k, pos[None], cfg.rope_theta)
     with jax.named_scope("kv_cache"):
         cache_k = _write_at(cache_k, at, k, (0, pos))
         cache_v = _write_at(cache_v, at, v, (0, pos))
@@ -294,9 +299,10 @@ def decode_self_attention(p: dict, x: jax.Array, cache_k: jax.Array,
                 and ck.shape[1] % mesh.shape["model"] == 0):
             from ..parallel.context import decode_attention_cache_layout
             ba = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-            o = decode_attention_cache_layout(
-                mesh, q[:, 0].astype(jnp.float32),
-                ck, cv, pos + 1, ba)
+            qf = q[:, 0].astype(jnp.float32)
+            if cfg.attn_scale is not None:   # the kernel scales by 1/sqrt(hd)
+                qf = qf * (cfg.attn_scale * math.sqrt(hd))
+            o = decode_attention_cache_layout(mesh, qf, ck, cv, pos + 1, ba)
             o = o.astype(x.dtype).reshape(b, 1, cfg.n_heads * hd)
             return _mm(o, p["wo"], cfg), cache_k, cache_v
     smax = ck.shape[1]
@@ -305,8 +311,9 @@ def decode_self_attention(p: dict, x: jax.Array, cache_k: jax.Array,
         kk = _repeat_kv(ck, n_rep).astype(jnp.float32)
         vv = _repeat_kv(cv, n_rep).astype(jnp.float32)
     with jax.named_scope("attention"):
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                            kk) / math.sqrt(hd)
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kk)
+        logits = (logits / math.sqrt(hd) if cfg.attn_scale is None
+                  else logits * cfg.attn_scale)
         mask = jnp.arange(smax)[None, None, None, :] <= pos
         logits = jnp.where(mask, logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
@@ -342,13 +349,16 @@ def mlp(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 # ================================= MoE =======================================
 def init_moe(key, cfg: ModelConfig) -> dict:
+    """The router over all ``cfg.moe_experts`` and the weights of the
+    experts this chip holds (``cfg.experts_held``)."""
     d, f, e = cfg.d_model, cfg.d_ff, cfg.moe_experts
+    held = cfg.experts_held[1]
     kr, ki, kg, ko = jax.random.split(key, 4)
     p = {"router": _dense_init(kr, d, (d, e)),
-         "wi": _dense_init(ki, d, (e, d, f)),
-         "wo": _dense_init(ko, f, (e, f, d))}
+         "wi": _dense_init(ki, d, (held, d, f)),
+         "wo": _dense_init(ko, f, (held, f, d))}
     if cfg.gated:
-        p["wg"] = _dense_init(kg, d, (e, d, f))
+        p["wg"] = _dense_init(kg, d, (held, d, f))
     return p
 
 
@@ -411,30 +421,85 @@ def moe(p: dict, x: jax.Array, cfg: ModelConfig,
     return y.reshape(b, s, d)
 
 
-def moe_dense(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Dropless MoE for tiny token counts (decode): every expert processes
-    all tokens; outputs combine by top-k gates. Exact (no capacity drops),
-    and with experts sharded on 'model' the combine is a psum — no dispatch
-    all-to-all, which at T=batch tokens/step is the cheaper schedule.
+def moe_dropless(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Dropless top-k MoE over the experts this chip holds.
+
+    The router scores all ``cfg.moe_experts`` (from f32 operands, so that
+    rounding does not reorder near-tied experts); each token takes its
+    top-k logits and a softmax over those k (softmax, then renormalised
+    over the k). The result is each held expert among a token's k times
+    its gate, summed: on a chip that holds every expert, the whole layer;
+    under expert parallelism, this chip's part of it. No token is dropped.
+
+    Under a mesh with a 'model' axis that divides the held experts, each
+    shard computes the part of its own experts for its batch rows and one
+    psum over 'model' adds the parts (the shard_map schedule of
+    ``parallel/moe.py``); the experts' weights never move.
+    """
+    first, held = cfg.experts_held
+    from ..parallel.logical import current_mesh
+    mesh = current_mesh()
+    if (mesh is None or "model" not in mesh.axis_names
+            or held % mesh.shape["model"]):
+        return _moe_held_part(p, x, cfg, first, held).astype(x.dtype)
+    from jax.sharding import PartitionSpec as P
+    m = mesh.shape["model"]
+    ba = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
+    if x.shape[0] % math.prod(mesh.shape[a] for a in ba):
+        ba = None                         # too few rows to split: replicate
+    names = ["router", "wi", "wo"] + (["wg"] if "wg" in p else [])
+    specs = {n: P(None, None) if n == "router" else P("model", None, None)
+             for n in names}
+
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
+             in_specs=(specs, P(ba, None, None)), out_specs=P(ba, None, None))
+    def shard_part(pl, xl):
+        lo = first + jax.lax.axis_index("model") * (held // m)
+        return jax.lax.psum(_moe_held_part(pl, xl, cfg, lo, held // m),
+                            "model").astype(xl.dtype)
+
+    return shard_part({n: p[n] for n in names}, x)
+
+
+def _moe_held_part(p: dict, x: jax.Array, cfg: ModelConfig, first,
+                   held: int) -> jax.Array:
+    """The dropless layer's sum over experts ``first .. first+held-1``, whose
+    weights ``p`` holds, in float32.
+
+    The (token, choice) pairs routed to these experts are sorted by expert
+    and run through ``jax.lax.ragged_dot`` in one pass of the most pairs
+    they can get, T · min(k, held) rows; the rows past the routed ones are
+    masked out of the combine. The pass is fixed: one sized for the
+    average load, with a branch to this one for a batch that overflows it,
+    did a seventh of the work at Granite's share but made run times vary
+    with each batch's routing (PERF.md §6).
     """
     b, s, d = x.shape
-    e, k = cfg.moe_experts, cfg.moe_top_k
-    xt = x.reshape(b * s, d)
-    logits = (xt @ p["router"].astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)
-    gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
-    combine = jnp.zeros((b * s, e), jnp.float32)
-    combine = combine.at[jnp.arange(b * s)[:, None], idx].add(gates)
-    h = jnp.einsum("td,edf->etf", xt, p["wi"].astype(x.dtype))
+    k = cfg.moe_top_k
+    t = b * s
+    rows = t * min(k, held)
+    xt = x.reshape(t, d)
+    logits = jnp.dot(xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)          # (T,E)
+    top, idx = jax.lax.top_k(logits, k)                            # (T,k)
+    gates = jax.nn.softmax(top, axis=-1).reshape(-1)               # (T·k,)
+    local = idx.reshape(-1) - first
+    # groups 0..held-1 are the held experts; group held, those elsewhere
+    group = jnp.where((local >= 0) & (local < held), local, held)
+    pair = jnp.argsort(group, stable=True)[:rows]                  # by expert
+    sizes = jnp.bincount(group, length=held + 1)[:held]
+    tok = pair // k
+    xs = xt[tok]                                                   # (rows,d)
+    h = jax.lax.ragged_dot(xs, p["wi"].astype(x.dtype), sizes)
     if "wg" in p:
-        g = jnp.einsum("td,edf->etf", xt, p["wg"].astype(x.dtype))
+        g = jax.lax.ragged_dot(xs, p["wg"].astype(x.dtype), sizes)
         h = jax.nn.silu(g) * h
     else:
         h = jax.nn.gelu(h)
-    y = jnp.einsum("etf,efd->etd", h, p["wo"].astype(x.dtype))
-    y = jnp.einsum("etd,te->td", y, combine.astype(x.dtype))
-    return y.reshape(b, s, d)
+    y = jax.lax.ragged_dot(h, p["wo"].astype(x.dtype), sizes)      # (rows,d)
+    routed = (jnp.arange(rows) < sizes.sum())[:, None]
+    y = jnp.where(routed, y.astype(jnp.float32) * gates[pair][:, None], 0.0)
+    return jnp.zeros((t, d), jnp.float32).at[tok].add(y).reshape(b, s, d)
 
 
 def moe_aux_loss(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -457,7 +522,7 @@ def init_ssm(key, cfg: ModelConfig) -> dict:
     h = d_in // cfg.ssm_head_dim
     conv_ch = d_in + 2 * n
     k1, k2, k3 = jax.random.split(key, 3)
-    return {
+    p = {
         "in_proj": _dense_init(k1, d, (d, 2 * d_in + 2 * n + h)),
         "conv_w": _dense_init(k2, cfg.ssm_conv, (cfg.ssm_conv, conv_ch)),
         "A_log": jnp.zeros((h,), jnp.float32),
@@ -466,6 +531,10 @@ def init_ssm(key, cfg: ModelConfig) -> dict:
         "norm_w": jnp.ones((d_in,), jnp.float32),
         "out_proj": _dense_init(k3, d_in, (d_in, d)),
     }
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = _dense_init(jax.random.fold_in(key, 3), cfg.ssm_conv,
+                                  (conv_ch,))
+    return p
 
 
 def _ssd_chunk_scan(xs, dt, Bm, Cm, A_log, chunk: int = 128):
@@ -524,37 +593,45 @@ def _ssd_chunk_scan(xs, dt, Bm, Cm, A_log, chunk: int = 128):
 
 
 def ssm_layer(p: dict, x: jax.Array, cfg: ModelConfig,
-              chunk: int = 128) -> jax.Array:
-    """Mamba2 block forward (training/prefill)."""
+              chunk: int = 128, with_state: bool = False):
+    """Mamba2 block forward (training/prefill). With ``with_state`` it
+    returns ``(y, final SSM state (B,H,P,N), conv tail (B,K-1,C))``: what
+    a decode step continues from."""
     b, s, d = x.shape
     d_in = cfg.ssm_expand * d
     n = cfg.ssm_state
     h = d_in // cfg.ssm_head_dim
     zxbcdt = _mm(x, p["in_proj"], cfg)
     z, xbc, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * n], axis=-1)
+    conv_tail = xbc[:, -(cfg.ssm_conv - 1):, :]
     # causal depthwise conv over [x;B;C]
-    xbc = _causal_conv(xbc, p["conv_w"].astype(x.dtype))
+    xbc = _causal_conv(xbc, p["conv_w"].astype(x.dtype), p.get("conv_b"))
     xbc = jax.nn.silu(xbc)
     xs, Bm, Cm = jnp.split(xbc, [d_in, d_in + n], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     xs = xs.reshape(b, s, h, cfg.ssm_head_dim)
     xs = shard(xs, "batch", "seq", "heads", None)
-    y, _ = _ssd_chunk_scan(xs.astype(jnp.float32), dt,
-                           Bm.astype(jnp.float32), Cm.astype(jnp.float32),
-                           p["A_log"], chunk=min(chunk, s))
+    y, state = _ssd_chunk_scan(xs.astype(jnp.float32), dt,
+                               Bm.astype(jnp.float32), Cm.astype(jnp.float32),
+                               p["A_log"].astype(jnp.float32),
+                               chunk=min(chunk, s))
     y = y + p["D"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(b, s, d_in).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm_w"])          # gated norm
-    return shard(_mm(y, p["out_proj"], cfg), "batch", "seq", None)
+    y = rmsnorm(y * jax.nn.silu(z), p["norm_w"], cfg.rms_eps)  # gated norm
+    y = shard(_mm(y, p["out_proj"], cfg), "batch", "seq", None)
+    return (y, state, conv_tail) if with_state else y
 
 
-def _causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Depthwise causal 1-D conv. x: (B,S,C); w: (K,C)."""
+def _causal_conv(x: jax.Array, w: jax.Array,
+                 bias: jax.Array | None = None) -> jax.Array:
+    """Depthwise causal 1-D conv. x: (B,S,C); w: (K,C); bias: (C,)."""
     k = w.shape[0]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     out = jnp.zeros_like(x)
     for i in range(k):
         out = out + xp[:, i:i + x.shape[1], :] * w[i]
+    if bias is not None:
+        out = out + bias.astype(x.dtype)
     return out
 
 
@@ -572,11 +649,13 @@ def ssm_decode_step(p: dict, x: jax.Array, state: jax.Array,
     w = p["conv_w"].astype(x.dtype)                       # (K, C)
     window = jnp.concatenate([conv_cache, xbc[:, None, :]], axis=1)  # (B,K,C)
     xbc_c = jnp.einsum("bkc,kc->bc", window, w)
+    if "conv_b" in p:
+        xbc_c = xbc_c + p["conv_b"].astype(x.dtype)
     conv_cache = window[:, 1:]
     xbc_c = jax.nn.silu(xbc_c)
     xs, Bm, Cm = jnp.split(xbc_c, [d_in, d_in + n], axis=-1)
     dtf = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])   # (B,H)
-    A = -jnp.exp(p["A_log"])                              # (H,)
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))          # (H,)
     dA = jnp.exp(dtf * A)                                 # (B,H)
     xs = xs.reshape(b, h, P).astype(jnp.float32)
     state = (state * dA[..., None, None]
@@ -584,5 +663,5 @@ def ssm_decode_step(p: dict, x: jax.Array, state: jax.Array,
     y = jnp.einsum("bhpn,bn->bhp", state, Cm.astype(jnp.float32))
     y = y + p["D"][None, :, None] * xs
     y = y.reshape(b, d_in).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm_w"])
+    y = rmsnorm(y * jax.nn.silu(z), p["norm_w"], cfg.rms_eps)
     return (y @ p["out_proj"].astype(x.dtype))[:, None, :], state, conv_cache

@@ -52,6 +52,9 @@ def _init_layer(key, cfg: ModelConfig, idx: int, cross_ok: bool) -> dict:
         p["ln2"] = norm_init(keys[4], cfg.d_model)
         if cfg.layer_is_moe(idx):
             p["moe"] = L.init_moe(keys[5], cfg)
+            if cfg.moe_shared_ff:
+                p["shared"] = L.init_mlp(
+                    keys[6], dataclasses.replace(cfg, d_ff=cfg.moe_shared_ff))
         else:
             p["mlp"] = L.init_mlp(keys[5], cfg)
     return p
@@ -103,26 +106,48 @@ def param_count(params) -> int:
 
 
 # ============================== block bodies =================================
+def _residual(cfg: ModelConfig, x: jax.Array, y: jax.Array) -> jax.Array:
+    """``x`` plus the branch ``y`` times ``cfg.residual_mult``."""
+    return x + (y if cfg.residual_mult == 1.0 else y * cfg.residual_mult)
+
+
+def _ffn(cfg: ModelConfig, lp: dict, x: jax.Array, dropless: bool):
+    """A layer's pre-norm feed-forward and its residual: the MLP, or the
+    routed experts (the dropless layer when ``dropless``, else the
+    capacity-bounded one) plus any shared expert."""
+    _, norm = L.make_norm(cfg)
+    if "mlp" in lp:
+        with jax.named_scope("mlp"):
+            return _residual(cfg, x, L.mlp(lp["mlp"], norm(lp["ln2"], x), cfg))
+    with jax.named_scope("moe"):
+        h = norm(lp["ln2"], x)
+        y = (L.moe_dropless(lp["moe"], h, cfg) if dropless
+             else L.moe(lp["moe"], h, cfg))
+    if "shared" in lp:
+        with jax.named_scope("moe_shared"):
+            y = y + L.mlp(lp["shared"], h, cfg)
+    return _residual(cfg, x, y)
+
+
 def _block_fwd(cfg: ModelConfig, bp: dict, x: jax.Array,
-               positions: jax.Array, memory: jax.Array | None) -> jax.Array:
+               positions: jax.Array, memory: jax.Array | None,
+               dropless: bool = False) -> jax.Array:
     _, norm = L.make_norm(cfg)
     for i in range(cfg.block_size):
         lp = bp[f"l{i}"]
         if cfg.layer_kind(i) == "attn":
             with jax.named_scope("attention"):
-                x = x + L.self_attention(lp["attn"], norm(lp["ln1"], x), cfg,
-                                         positions)
+                x = _residual(cfg, x, L.self_attention(
+                    lp["attn"], norm(lp["ln1"], x), cfg, positions))
             if cfg.layer_is_cross(i) and memory is not None:
                 x = x + L.cross_attention(lp["xattn"], norm(lp["lnx"], x),
                                           memory, cfg)
         else:
-            x = x + L.ssm_layer(lp["ssm"], norm(lp["ln1"], x), cfg)
+            with jax.named_scope("ssm"):
+                x = _residual(cfg, x, L.ssm_layer(lp["ssm"],
+                                                  norm(lp["ln1"], x), cfg))
         if cfg.d_ff:
-            if cfg.layer_is_moe(i):
-                x = x + L.moe(lp["moe"], norm(lp["ln2"], x), cfg)
-            else:
-                with jax.named_scope("mlp"):
-                    x = x + L.mlp(lp["mlp"], norm(lp["ln2"], x), cfg)
+            x = _ffn(cfg, lp, x, dropless)
         x = shard(x, "batch", "seq", None)
     return x
 
@@ -161,25 +186,41 @@ def encode(cfg: ModelConfig, params: dict, frames: jax.Array) -> jax.Array:
     return norm(params["enc_final_norm"], x)
 
 
-def forward(cfg: ModelConfig, params: dict, tokens: jax.Array,
-            memory: jax.Array | None = None,
-            remat: bool = True) -> jax.Array:
-    """Decoder forward. tokens: (B, S) int32; memory: (B, M, d) for
-    VLM image embeddings or encoder output. Returns logits (B, S, V)."""
+def _embed(cfg: ModelConfig, params: dict, tokens: jax.Array) -> jax.Array:
     compute_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     x = params["embed"][tokens].astype(compute_dtype)
-    x = shard(x, "batch", "seq", None)
+    return x if cfg.embed_mult == 1.0 else x * cfg.embed_mult
+
+
+def _lm_head(cfg: ModelConfig, params: dict, dtype) -> jax.Array:
+    return (params["embed"].T if cfg.tie_embeddings
+            else params["lm_head"]).astype(dtype)
+
+
+def _scale_logits(cfg: ModelConfig, logits: jax.Array) -> jax.Array:
+    return logits if cfg.logits_div == 1.0 else logits / cfg.logits_div
+
+
+def forward(cfg: ModelConfig, params: dict, tokens: jax.Array,
+            memory: jax.Array | None = None,
+            remat: bool = True, dropless: bool = False) -> jax.Array:
+    """Decoder forward. tokens: (B, S) int32; memory: (B, M, d) for
+    VLM image embeddings or encoder output. Returns logits (B, S, V).
+    ``dropless`` runs expert layers through the dropless layer (serving)
+    and not the capacity-bounded one (training)."""
+    compute_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    x = shard(_embed(cfg, params, tokens), "batch", "seq", None)
     pos = jnp.arange(tokens.shape[1])
     if memory is not None:
         memory = memory.astype(compute_dtype)
-    x = _scan_stack(lambda bp, h: _block_fwd(cfg, bp, h, pos, memory),
-                    x, params["stack"], remat=remat, policy=cfg.remat)
+    x = _scan_stack(
+        lambda bp, h: _block_fwd(cfg, bp, h, pos, memory, dropless),
+        x, params["stack"], remat=remat, policy=cfg.remat)
     _, norm = L.make_norm(cfg)
     with jax.named_scope("lm_head"):
         x = norm(params["final_norm"], x)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"]).astype(compute_dtype)
-        logits = shard(x @ head, "batch", "seq", "vocab")
+        head = _lm_head(cfg, params, compute_dtype)
+        logits = shard(_scale_logits(cfg, x @ head), "batch", "seq", "vocab")
     return logits
 
 
@@ -224,7 +265,12 @@ def cache_spec(cfg: ModelConfig) -> CacheSpec:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
-               dtype=jnp.bfloat16) -> dict:
+               dtype=None) -> dict:
+    """Zeroed decode state: K/V per attention layer and the conv window per
+    SSM layer in ``dtype`` (default: the compute dtype), SSM states in
+    float32."""
+    if dtype is None:
+        dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     spec = cache_spec(cfg)
     nb = cfg.n_blocks
     cache: dict = {}
@@ -258,24 +304,23 @@ def _block_decode(cfg: ModelConfig, bp: dict, cache: dict, blk: jax.Array,
                 h, cache["k"], cache["v"] = L.decode_self_attention(
                     lp["attn"], norm(lp["ln1"], x), cache["k"], cache["v"],
                     (blk, spec.attn_slots[i]), pos, cfg)
-                x = x + h
+                x = _residual(cfg, x, h)
             if cfg.layer_is_cross(i) and memory is not None:
                 x = x + L.cross_attention(lp["xattn"], norm(lp["lnx"], x),
                                           memory, cfg)
         else:
             at = (blk, spec.ssm_slots[i])
-            h, st, cc = L.ssm_decode_step(
-                lp["ssm"], norm(lp["ln1"], x), L._at(cache["ssm"], at),
-                L._at(cache["conv"], at), cfg)
-            x = x + h
-            cache["ssm"] = L._write_at(cache["ssm"], at, st)
-            cache["conv"] = L._write_at(cache["conv"], at, cc)
+            with jax.named_scope("ssm_state"):
+                st, cc = L._at(cache["ssm"], at), L._at(cache["conv"], at)
+            with jax.named_scope("ssm"):
+                h, st, cc = L.ssm_decode_step(lp["ssm"], norm(lp["ln1"], x),
+                                              st, cc, cfg)
+                x = _residual(cfg, x, h)
+            with jax.named_scope("ssm_state"):
+                cache["ssm"] = L._write_at(cache["ssm"], at, st)
+                cache["conv"] = L._write_at(cache["conv"], at, cc)
         if cfg.d_ff:
-            if cfg.layer_is_moe(i):    # dropless at T=1
-                x = x + L.moe_dense(lp["moe"], norm(lp["ln2"], x), cfg)
-            else:
-                with jax.named_scope("mlp"):
-                    x = x + L.mlp(lp["mlp"], norm(lp["ln2"], x), cfg)
+            x = _ffn(cfg, lp, x, dropless=True)
     return x, cache
 
 
@@ -292,6 +337,8 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
     Returns (logits (B, V), updated cache)."""
     compute_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     x = params["embed"][token][:, None, :].astype(compute_dtype)  # (B,1,d)
+    if cfg.embed_mult != 1.0:
+        x = x * cfg.embed_mult
     if memory is not None:
         memory = memory.astype(compute_dtype)
 
@@ -301,16 +348,17 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
         return (y, c, blk + 1), None
 
     # the scan's own ops (each layer's weights sliced from the stack) are
-    # named layers; the cache row writes and reads inside are kv_cache
+    # named layers; the cache row writes and reads inside are kv_cache (and
+    # ssm_state)
     with jax.named_scope("layers"):
         (x, new_cache, _), _ = jax.lax.scan(
             step, (x, cache, jnp.int32(0)), params["stack"])
     _, norm = L.make_norm(cfg)
     with jax.named_scope("lm_head"):
         x = norm(params["final_norm"], x)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"]).astype(compute_dtype)
-        logits = shard(x[:, 0, :] @ head, "batch", "vocab")
+        head = _lm_head(cfg, params, compute_dtype)
+        logits = shard(_scale_logits(cfg, x[:, 0, :] @ head), "batch",
+                       "vocab")
     return logits, new_cache
 
 
@@ -322,7 +370,7 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array,
     equivalent to fused prefill for the dry-run's purposes, and exactly
     correct w.r.t. decode_step (tested).
     """
-    logits = forward(cfg, params, tokens, memory=memory)
+    logits = forward(cfg, params, tokens, memory=memory, dropless=True)
     with jax.named_scope("fill_cache"):
         cache = init_cache(cfg, tokens.shape[0], tokens.shape[1])
         cache = _fill_cache(cfg, params, tokens, cache, memory)
@@ -333,7 +381,7 @@ def _fill_cache(cfg: ModelConfig, params: dict, tokens: jax.Array,
                 cache: dict, memory: jax.Array | None):
     """Recompute per-layer inputs and write K/V + SSM states into the cache."""
     compute_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    x = params["embed"][tokens].astype(compute_dtype)
+    x = _embed(cfg, params, tokens)
     if memory is not None:
         memory = memory.astype(compute_dtype)
     pos = jnp.arange(tokens.shape[1])
@@ -354,50 +402,27 @@ def _fill_cache(cfg: ModelConfig, params: dict, tokens: jax.Array,
                     b, s, cfg.n_kv_heads, cfg.hd)
                 v = (xin @ lp["attn"]["wv"].astype(xin.dtype)).reshape(
                     b, s, cfg.n_kv_heads, cfg.hd)
-                k = L.apply_rope(k, pos, cfg.rope_theta)
+                if cfg.pos_emb == "rope":
+                    k = L.apply_rope(k, pos, cfg.rope_theta)
                 nc["k"] = nc["k"].at[slot, :, :s].set(k.astype(nc["k"].dtype))
                 nc["v"] = nc["v"].at[slot, :, :s].set(v.astype(nc["v"].dtype))
-                h = h + L.self_attention(lp["attn"], xin, cfg, pos)
+                h = _residual(cfg, h, L.self_attention(lp["attn"], xin, cfg,
+                                                       pos))
                 if cfg.layer_is_cross(i) and memory is not None:
                     h = h + L.cross_attention(lp["xattn"], norm(lp["lnx"], h),
                                               memory, cfg)
             else:
                 slot = spec.ssm_slots[i]
-                xin = norm(lp["ln1"], h)
-                y, st, conv_tail = _ssm_with_state(lp["ssm"], xin, cfg)
+                with jax.named_scope("ssm"):
+                    y, st, conv_tail = L.ssm_layer(
+                        lp["ssm"], norm(lp["ln1"], h), cfg, with_state=True)
+                    h = _residual(cfg, h, y)
                 nc["ssm"] = nc["ssm"].at[slot].set(st)
                 nc["conv"] = nc["conv"].at[slot].set(
                     conv_tail.astype(nc["conv"].dtype))
-                h = h + y
             if cfg.d_ff:
-                hh = norm(lp["ln2"], h)
-                h = h + (L.moe(lp["moe"], hh, cfg) if cfg.layer_is_moe(i)
-                         else L.mlp(lp["mlp"], hh, cfg))
+                h = _ffn(cfg, lp, h, dropless=True)
         return h, nc
 
     _, new_cache = jax.lax.scan(step, x, (params["stack"], cache))
     return new_cache
-
-
-def _ssm_with_state(p: dict, x: jax.Array, cfg: ModelConfig):
-    """ssm_layer variant that also returns (final_state, conv_tail)."""
-    b, s, d = x.shape
-    d_in = cfg.ssm_expand * d
-    n = cfg.ssm_state
-    h = d_in // cfg.ssm_head_dim
-    zxbcdt = x @ p["in_proj"].astype(x.dtype)
-    z, xbc, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * n], axis=-1)
-    conv_tail = xbc[:, -(cfg.ssm_conv - 1):, :]
-    xbc = L._causal_conv(xbc, p["conv_w"].astype(x.dtype))
-    xbc = jax.nn.silu(xbc)
-    xs, Bm, Cm = jnp.split(xbc, [d_in, d_in + n], axis=-1)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
-    xs = xs.reshape(b, s, h, cfg.ssm_head_dim)
-    y, state = L._ssd_chunk_scan(xs.astype(jnp.float32), dt,
-                                 Bm.astype(jnp.float32),
-                                 Cm.astype(jnp.float32),
-                                 p["A_log"], chunk=min(128, s))
-    y = y + p["D"][None, None, :, None] * xs.astype(jnp.float32)
-    y = y.reshape(b, s, d_in).astype(x.dtype)
-    y = L.rmsnorm(y * jax.nn.silu(z), p["norm_w"])
-    return (y @ p["out_proj"].astype(x.dtype)), state, conv_tail

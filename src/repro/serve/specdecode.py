@@ -36,14 +36,16 @@ def speculative_generate(target_cfg: ModelConfig, target_params,
         dseq = seq
         proposal = []
         for _ in range(k):
-            dlogits = forward(draft_cfg, draft_params, dseq, remat=False)
+            dlogits = forward(draft_cfg, draft_params, dseq, remat=False,
+                              dropless=True)
             nxt = jnp.argmax(dlogits[:, -1], -1).astype(jnp.int32)
             proposal.append(int(nxt[0]))
             dseq = jnp.concatenate([dseq, nxt[:, None]], axis=1)
         # target verifies in one pass over seq + proposal
         ver_in = jnp.concatenate(
             [seq, jnp.asarray([proposal], jnp.int32)], axis=1)
-        tlogits = forward(target_cfg, target_params, ver_in, remat=False)
+        tlogits = forward(target_cfg, target_params, ver_in, remat=False,
+                          dropless=True)
         target_calls += 1
         s0 = seq.shape[1]
         greedy = jnp.argmax(tlogits[0, s0 - 1:s0 - 1 + k], -1)

@@ -118,11 +118,10 @@ class ExecutableTwin:
     independently (graph builders vs runtime config), so this is the tripwire
     that keeps them from drifting apart.
 
-    ``dense_experts`` mirrors the runtime's decode-time MoE semantics: at one
-    token per request the engine runs every expert densely
-    (``repro.models.layers.moe_dense`` — dropless, no dispatch), so the
-    matched analytical graph prices all ``moe_experts`` experts, not
-    ``moe_top_k``.
+    ``dense_experts`` prices all ``moe_experts`` experts, not ``moe_top_k``:
+    the engine's dropless decode layer (``repro.models.layers.
+    moe_dropless``) touches nearly every expert at a decode batch, and
+    XLA's CPU lowering of its ``ragged_dot`` computes every expert densely.
     """
 
     scenario: str

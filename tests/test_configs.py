@@ -17,6 +17,7 @@ ASSIGNED = {
     "jamba_v01_52b": (32, 4096, 32, 8, 14336, 65536, 16, 2),
     "seamless_m4t_medium": (12, 1024, 16, 16, 4096, 256206, 0, 0),
     "mamba2_130m": (24, 768, 0, 0, 0, 50280, 0, 0),
+    "granite_4_h_small": (40, 4096, 32, 8, 768, 100352, 72, 10),
 }
 
 
@@ -45,6 +46,21 @@ def test_family_flags():
     assert get_config("qwen3_moe_235b").family == "moe"
 
 
+def test_granite_pattern_and_share():
+    """Granite-4.0-H-Small: attention at layers 5, 15, 25, 35 of 40 (one
+    per period of 10), MoE with a shared expert on every layer; its chip's
+    share holds one period and experts 0-8 of 72, routing over all 72."""
+    cfg = get_config("granite_4_h_small")
+    assert [i for i in range(cfg.n_layers)
+            if cfg.layer_kind(i % cfg.block_size) == "attn"] == [5, 15, 25, 35]
+    assert cfg.moe_shared_ff == 1536 and cfg.pos_emb == "nope"
+    assert all(cfg.layer_is_moe(i) for i in range(cfg.block_size))
+    share = get_config("granite_4_h_small_ep8")
+    assert share.n_layers == cfg.block_size == 10
+    assert share.experts_held == (0, 9) and share.moe_experts == 72
+    assert cfg.experts_held == (0, 72)
+
+
 def test_jamba_interleave():
     """Jamba: mamba:attention 1:7 interleave (one attn layer per 8), MoE on
     alternating layers (16e top-2)."""
@@ -69,7 +85,7 @@ def test_shape_table():
 def test_long_context_cells_only_for_subquadratic():
     for arch in ASSIGNED:
         names = cells(arch)
-        if arch in ("mamba2_130m", "jamba_v01_52b"):
+        if arch in ("mamba2_130m", "jamba_v01_52b", "granite_4_h_small"):
             assert "long_500k" in names, arch
         else:
             assert "long_500k" not in names, arch

@@ -123,7 +123,7 @@ def test_moe_capacity_drops_are_bounded():
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, cfg.d_model),
                           jnp.float32)
     y_cap = L.moe(p, x, cfg, capacity_factor=8.0)   # large cap: no drops
-    y_dense = L.moe_dense(p, x, cfg)
+    y_dense = L.moe_dropless(p, x, cfg)
     np.testing.assert_allclose(np.asarray(y_cap), np.asarray(y_dense),
                                rtol=2e-2, atol=2e-2)
 

@@ -189,3 +189,58 @@ def test_moe_expert_parallel_lowms_to_collectives():
     assert total > 0, "expert parallelism emitted no collectives"
     print("EP collective bytes", total)
     """)
+
+
+@pytest.mark.parametrize("arch", ["olmoe_1b_7b", "jamba_v01_52b"])
+def test_dropless_moe_serves_on_a_mesh_as_on_one_device(arch):
+    """A MoE config served on a (2, 4) mesh, its experts split over 'model'
+    as ``param_shardings`` places them: prefill and three decode steps give
+    the single-device logits, and the launcher serves the single-device
+    engine's greedy tokens (that no expert is gathered onto a chip is
+    checked on the TPU's compiler, ``test_tpu_compile.py``). Float32,
+    so sharding moves only the order of summation (about 1e-5 on logits of
+    about 4; a lost or doubled expert moves them by 1e-1 and more)."""
+    _run("""
+    import dataclasses
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.configs import get_config
+    from repro.models import decode_step, init_params, prefill
+    from repro.parallel.logical import use_rules
+    from repro.launch.mesh import make_axis_rules, make_mesh
+    from repro.launch.serve import run_serve
+    from repro.launch.shardings import param_shardings
+    from repro.serve.engine import ServeEngine
+
+    cfg = dataclasses.replace(get_config("ARCH", smoke=True),
+                              dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
+                                 cfg.vocab)
+
+    def serve(params):
+        fill = jax.jit(lambda p, t: prefill(cfg, p, t))
+        step = jax.jit(lambda p, c, t, pos: decode_step(cfg, p, c, t, pos))
+        logits, cache = fill(params, prompts)
+        room = [(0, 0)] * 3 + [(0, 3), (0, 0), (0, 0)]   # 3 more K/V rows
+        cache.update({n: jnp.pad(cache[n], room) for n in ("k", "v")
+                      if n in cache})
+        out, tok = [logits], jnp.argmax(logits[:, -1], -1)
+        for pos in range(16, 19):
+            logits, cache = step(params, cache, tok, jnp.int32(pos))
+            out.append(logits)
+            tok = jnp.argmax(logits, -1)
+        return out
+
+    ref = serve(params)
+    mesh = make_mesh((2, 4), ("data", "model"))
+    with mesh, use_rules(make_axis_rules(mesh, cfg), mesh):
+        got = serve(jax.device_put(params, param_shardings(cfg, mesh)))
+    for a, b in zip(ref, got):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=0, atol=1e-4)
+
+    engine = ServeEngine(cfg, params, max_batch=4, max_len=16 + 8 + 1)
+    want = engine.generate(prompts, n_tokens=8).tokens
+    assert run_serve(cfg, tokens=8, mesh_spec="2x4").tokens == want
+    print("mesh serving OK")
+    """.replace("ARCH", arch))

@@ -10,6 +10,7 @@ library; where it cannot be described, every test here skips.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import re
 
@@ -151,3 +152,57 @@ def test_olmo_1b_engine_decode_step_writes_the_cache_in_place(one_chip):
         assert op == "fusion" and called, name
         root = [ln for ln in comps[called.group(1)] if ln.startswith("ROOT")]
         assert " dynamic-update-slice(" in root[0], (name, root)
+
+
+@pytest.mark.parametrize("arch", ["olmoe_1b_7b", "jamba_v01_52b"])
+def test_served_experts_stay_on_their_chips_on_a_mesh(topo, arch):
+    """On the described 2x2 mesh, with each chip holding its share of the
+    experts as ``param_shardings`` places them, the serving prefill and
+    decode step gather no layer's experts onto one chip: each chip runs
+    its own experts and one psum adds the parts. (GSPMD alone gathers
+    every expert's weights for ``ragged_dot``.)"""
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import make_axis_rules
+    from repro.launch.shardings import param_shardings
+    from repro.models import prefill
+    from repro.parallel.logical import use_rules
+
+    cfg = get_config(arch, smoke=True)
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=topo.devices)
+    batch, prompt = 4, 64
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), tree, shardings)
+
+    whole = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("data"))
+    with mesh, use_rules(make_axis_rules(mesh, cfg), mesh):
+        params = placed(jax.eval_shape(
+            lambda k: init_params(cfg, k),
+            jax.ShapeDtypeStruct((2,), jnp.uint32)),
+            param_shardings(cfg, mesh))
+        cache = jax.eval_shape(lambda: init_cache(cfg, batch, prompt))
+        cache = placed(cache, jax.tree.map(lambda _: whole, cache))
+        prompts = jax.ShapeDtypeStruct((batch, prompt), jnp.int32,
+                                       sharding=rows)
+        token = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=rows)
+        pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)
+        programs = [
+            jax.jit(lambda p, t: prefill(cfg, p, t)).lower(params, prompts),
+            jax.jit(lambda p, c, t, i: decode_step(cfg, p, c, t, i)).lower(
+                params, cache, token, pos)]
+        hlos = [lowered.compile().as_text() for lowered in programs]
+
+    one_layer = cfg.moe_experts * cfg.d_model * cfg.d_ff
+    for hlo in hlos:
+        assert "psum" in hlo or "all-reduce" in hlo
+        for line in hlo.splitlines():
+            if " all-gather(" in line or " all-gather-start(" in line:
+                out = line.split("=", 1)[1].split(" all-gather")[0]
+                for dims in re.findall(r"\w+\[([\d,]*)\]", out):
+                    size = math.prod(int(n) for n in dims.split(",") if n)
+                    assert size < one_layer, ("experts gathered", line)
