@@ -281,10 +281,24 @@ def decode_self_attention(p: dict, x: jax.Array, cache_k: jax.Array,
     """
     b, _, d = x.shape
     hd = cfg.hd
+    # A weight stored wider than the activations is cast inside its own dot
+    # fusion only while the dot writes plain (batch, features) rows: given
+    # the cache's (heads, batch, hd) order, the TPU's compiler re-lays the
+    # weight out, a cast copy of it, to make heads a batch dimension. The
+    # barrier keeps that order off the dot (and, unlike a layout
+    # constraint, leaves the output's sharding alone).
+    wide = p["wq"].dtype.itemsize > x.dtype.itemsize
+
+    def project(w, heads):
+        y = _mm(x, w, cfg)
+        if wide:
+            y = jax.lax.optimization_barrier(y)
+        return y.reshape(b, 1, heads, hd)
+
     with jax.named_scope("attention"):
-        q = _mm(x, p["wq"], cfg).reshape(b, 1, cfg.n_heads, hd)
-        k = _mm(x, p["wk"], cfg).reshape(b, 1, cfg.n_kv_heads, hd)
-        v = _mm(x, p["wv"], cfg).reshape(b, 1, cfg.n_kv_heads, hd)
+        q = project(p["wq"], cfg.n_heads)
+        k = project(p["wk"], cfg.n_kv_heads)
+        v = project(p["wv"], cfg.n_kv_heads)
         if cfg.pos_emb == "rope":
             q = apply_rope(q, pos[None], cfg.rope_theta)
             k = apply_rope(k, pos[None], cfg.rope_theta)

@@ -347,12 +347,19 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
         y, c = _block_decode(cfg, bp, c, blk, y, pos, memory)
         return (y, c, blk + 1), None
 
+    # Weights stored wider than the compute dtype (f32 params, bf16
+    # compute) unroll the layer loop, so that each matmul reads its layer's
+    # slice and casts it inside its own fusion: in a loop, XLA hoists the
+    # cast of each whole weight stack out of it, and every step writes and
+    # reads back a compute-dtype copy of the weights.
+    wide = any(w.dtype.itemsize > jnp.dtype(compute_dtype).itemsize
+               for w in jax.tree.leaves(params["stack"]))
     # the scan's own ops (each layer's weights sliced from the stack) are
     # named layers; the cache row writes and reads inside are kv_cache (and
     # ssm_state)
     with jax.named_scope("layers"):
         (x, new_cache, _), _ = jax.lax.scan(
-            step, (x, cache, jnp.int32(0)), params["stack"])
+            step, (x, cache, jnp.int32(0)), params["stack"], unroll=wide)
     _, norm = L.make_norm(cfg)
     with jax.named_scope("lm_head"):
         x = norm(params["final_norm"], x)

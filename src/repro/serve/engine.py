@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import re
 import time
 from typing import NamedTuple
 
@@ -125,6 +126,46 @@ def aliased_share(step: DecodeStep, cache) -> float:
     return step.run.memory_analysis().alias_size_in_bytes / cache_bytes
 
 
+def fused_weight_share(step: DecodeStep, params) -> float:
+    """The share of the stacked layers' matrix weight bytes (the leaves of
+    ``params["stack"]`` with two or more dimensions a layer) that the
+    compiled decode ``step`` reads as stored (1.0: every matmul casts its
+    weight inside its own fusion). A weight counts as cast apart when an
+    op outside a fusion's body writes an array of its shape, the whole
+    stack or one layer's slice, in another dtype."""
+    hlo = step.run.as_text()
+    bodies = set(re.findall(r" fusion\(.*?calls=%([\w.-]+)", hlo))
+    written, comp = set(), None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        op = re.match(r"\s+(?:ROOT )?%\S+ = (.+?) ([a-z][\w-]*)\(", line)
+        if comp in bodies or not op or op.group(2) in _PASS_THROUGH:
+            continue
+        written.update(re.findall(r"(\w+)\[([\d,]*)\]", op.group(1)))
+    total = cast_apart = 0
+    for w in jax.tree.leaves(params["stack"]):
+        if w.ndim < 3:
+            continue
+        n = w.size * w.dtype.itemsize
+        total += n
+        shapes = {",".join(map(str, dims))
+                  for dims in (w.shape, w.shape[1:], (1, *w.shape[1:]))}
+        stored = _HLO_TYPES.get(w.dtype.name, w.dtype.name)
+        if any(dims in shapes and t != stored for t, dims in written):
+            cast_apart += n
+    return 1.0 - cast_apart / total if total else 1.0
+
+
+#: Ops that hand a buffer on without writing one.
+_PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                 "while", "conditional", "call"}
+#: HLO's names of the weight dtypes.
+_HLO_TYPES = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}
+
+
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, max_batch: int = 8,
                  max_len: int = 1024):
@@ -136,7 +177,8 @@ class ServeEngine:
         # memory operand — tests assert a memory change reaches the logits
         self._prefill = jax.jit(
             lambda p, t, mem: prefill(cfg, p, t, memory=mem))
-        self._steps: dict = {}     # (batch, memory shape) -> (step, share)
+        # (batch, memory shape) -> (step, aliased share, fused share)
+        self._steps: dict = {}
         self._calls = itertools.count()
 
     # --- shared plumbing ----------------------------------------------------
@@ -164,15 +206,17 @@ class ServeEngine:
 
     def _decoder(self, cache: dict, tok: jax.Array, memory):
         """The compiled decode step for this batch and memory shape (see
-        :func:`compile_decode`, compiled on first use), its share of
-        aliased cache bytes, and ``cache`` moved into the step's order."""
+        :func:`compile_decode`, compiled on first use), its shares of
+        aliased cache bytes and of weight bytes read as stored, and
+        ``cache`` moved into the step's order."""
         key = (tok.shape, None if memory is None else memory.shape)
         if key not in self._steps:
             step = compile_decode(self.cfg, self.params, cache, tok,
                                   jnp.int32(0), memory)
-            self._steps[key] = step, aliased_share(step, cache)
-        step, share = self._steps[key]
-        return step.run, share, step.enter(cache)
+            self._steps[key] = (step, aliased_share(step, cache),
+                                fused_weight_share(step, self.params))
+        step, aliased, fused = self._steps[key]
+        return step.run, aliased, fused, step.enter(cache)
 
     @staticmethod
     def _sample(logits: jax.Array, temperature: float,
@@ -205,7 +249,9 @@ class ServeEngine:
         engine-local call id, and the counter ``serve.exposed_s``: host
         seconds from each sync's return to the return of the engine's next
         dispatch (after the last sync, to the call's return), in which the
-        device holds none of its work.
+        device holds none of its work. A call that decodes also adds the
+        compiled step's :func:`aliased_share` to ``serve.cache_aliased``
+        and its :func:`fused_weight_share` to ``serve.weights_fused``.
         """
         b, s = prompts.shape
         self._check_window(s, n_tokens)
@@ -227,9 +273,10 @@ class ServeEngine:
             for pos in range(s, s + n_tokens - 1):
                 with span("serve.decode_step"):
                     if pos == s:
-                        step, share, cache = self._decoder(cache, toks[-1],
-                                                           memory)
-                        count("serve.cache_aliased", share)
+                        step, aliased, fused, cache = self._decoder(
+                            cache, toks[-1], memory)
+                        count("serve.cache_aliased", aliased)
+                        count("serve.weights_fused", fused)
                     logits_i, cache = step(self.params, cache, toks[-1],
                                            jnp.int32(pos), memory)
                     if pos == s:      # the first dispatch after the first sync
@@ -274,7 +321,7 @@ class ServeEngine:
         jax.block_until_ready(tok)
         ttft = time.perf_counter() - t0
 
-        step, _, cache = self._decoder(cache, tok, memory)
+        step, _, _, cache = self._decoder(cache, tok, memory)
         pos = s
         for _ in range(warmup):
             logits_i, cache = step(self.params, cache, tok, jnp.int32(pos),

@@ -73,7 +73,7 @@ def test_decode_through_the_hybrid_cache_matches_the_reference():
     logits, cache0 = engine._prefill(params, toks[:, :s], None)
     cache = engine._rehome(cache0, 2, s)
     assert {"k", "v", "ssm", "conv"} <= set(cache)
-    step, share, cache = engine._decoder(cache, toks[:, s], None)
+    step, share, _, cache = engine._decoder(cache, toks[:, s], None)
     got = [logits[:, -1]]
     for pos in range(s, s + n):
         out, cache = step(params, cache, toks[:, pos], jnp.int32(pos), None)

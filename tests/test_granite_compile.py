@@ -1,5 +1,5 @@
 """Granite-4.0-H-Small's one-chip share compiles for a described TPU v5e,
-and olmo_1b's programs are untouched by the fields Granite added.
+and the lowered programs of olmo_1b and of the share are pinned by hash.
 
 Nothing runs: programs are lowered from shapes placed on one chip of a
 described ``v5e:2x2`` topology and compiled by the TPU's own compiler. The
@@ -19,7 +19,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import decode_step, init_cache, init_params, prefill
-from repro.serve.engine import aliased_share, compile_decode
+from repro.serve.engine import (aliased_share, compile_decode,
+                                fused_weight_share)
 
 #: Device memory of one TPU v5e chip.
 V5E_HBM = 16 * 2**30
@@ -27,15 +28,25 @@ V5E_HBM = 16 * 2**30
 BATCH, PROMPT, MAX_LEN = 16, 1024, 1152
 #: sha256 of olmo_1b's (SwiGLU, as the benchmark runs it) lowered programs
 #: at BATCH x MAX_LEN (decode step) and BATCH x PROMPT (prefill), without
-#: debug locations, under JAX 0.9.0, as they were before Granite's fields
-#: (NoPE, attention scale, muP multipliers, shared expert, conv bias, held
-#: experts, dropless serving MoE) came in: every default leaves them alone.
+#: debug locations, under JAX 0.9.0. The prefill is as it was before
+#: Granite's fields (NoPE, attention scale, muP multipliers, shared expert,
+#: conv bias, held experts, dropless serving MoE) came in: every default
+#: leaves it alone. The decode step changed once since, on purpose: its f32
+#: weights under bf16 activations unroll the layer loop and put the q/k/v
+#: projections' outputs behind an optimization barrier, so that each matmul
+#: casts its layer's weight inside its own fusion and no bf16 copy of a
+#: weight is made.
 OLMO_HLO = {
     "decode":
-        "5794ac3ea02ac10010f325bc5ca9b24c8c19c0b5d8df374938289e43cc3266c0",
+        "6f6f571bd211ba15a35b34e0d1106bf0a57b6a70a8e019d7ab536545cc19bea4",
     "prefill":
         "c9eb159140ee9322940f5f4a49fd8b615c94cc4eabcb5b55df36a0e53f5a1131",
 }
+#: sha256 of the chip's share's lowered decode step at BATCH x MAX_LEN, as
+#: it was before f32 weights took the unrolled path: bf16 weights keep the
+#: rolled layer loop.
+GRANITE_DECODE_HLO = (
+    "b894d1deefee6221ad0086ac2475a885bc894f008d145b601af1e6fd7cd6ee04")
 
 
 @pytest.fixture(scope="module")
@@ -85,13 +96,15 @@ def _bytes(compiled) -> int:
 def test_granite_share_decode_step_fits_and_aliases_the_hybrid_cache(
         one_chip):
     """The engine's donated decode step of the chip's share at 16 x 1,152
-    fits one chip and writes every byte of the hybrid cache (K/V, SSM
-    state, conv window) back into its argument's buffers."""
+    fits one chip, writes every byte of the hybrid cache (K/V, SSM
+    state, conv window) back into its argument's buffers, and reads every
+    bf16 weight as stored."""
     cfg = get_config("granite_4_h_small_ep8")
     params, cache, token, pos, _ = _program(cfg, one_chip)
     assert set(cache) == {"k", "v", "ssm", "conv"}
     step = compile_decode(cfg, params, cache, token, pos)
     assert aliased_share(step, cache) == 1.0
+    assert fused_weight_share(step, params) == 1.0
     assert 0.25 * V5E_HBM < _bytes(step.run) < V5E_HBM
 
 
@@ -117,3 +130,12 @@ def test_olmo_1b_programs_are_unchanged(one_chip, program):
                                                                  prompts)
     text = lowered.as_text(debug_info=False)
     assert hashlib.sha256(text.encode()).hexdigest() == OLMO_HLO[program]
+
+
+def test_granite_share_decode_program_is_unchanged(one_chip):
+    cfg = get_config("granite_4_h_small_ep8")
+    params, cache, token, pos, _ = _program(cfg, one_chip)
+    lowered = jax.jit(lambda p, c, t, i: decode_step(cfg, p, c, t, i)
+                      ).lower(params, cache, token, pos)
+    text = lowered.as_text(debug_info=False)
+    assert hashlib.sha256(text.encode()).hexdigest() == GRANITE_DECODE_HLO

@@ -12,7 +12,7 @@ from repro.core.serving import (expected_accepted, serving_sweep,
                                 speculative_throughput)
 from repro.models import decode_step, init_params, synth_batch
 from repro.models.transformer import _memory_from_batch
-from repro.serve.engine import ServeEngine
+from repro.serve.engine import DecodeStep, ServeEngine, fused_weight_share
 from repro.systems.chips import HBM_V5E, ICI, SN40L
 from repro.systems.system import SystemSpec
 from repro.systems.topology import torus2d
@@ -90,6 +90,58 @@ def test_generate_matches_undonated_decode_steps(arch):
     assert got == want
 
 
+@pytest.mark.parametrize("arch", ["olmo_1b", "mistral_nemo_12b"])
+def test_f32_weights_decode_as_their_bf16_cast(arch):
+    """A decode step on f32 weights with bf16 activations (the layer loop
+    unrolled, each matmul casting its layer's slice in its own fusion)
+    returns the logits and cache, bit for bit, of the same step on the
+    weights cast to bf16 beforehand (the rolled loop), and the engine
+    serves the same greedy tokens from either. The two configs' f32
+    parameters outside the matmuls are norm weights of ones, which the
+    cast leaves as they are."""
+    cfg = get_config(arch, smoke=True)
+    params = init_params(cfg, KEY)
+    cast = jax.tree.map(lambda w: w.astype(jnp.bfloat16), params)
+    b, s, n = 2, 8, 5
+    prompts = jax.random.randint(KEY, (b, s), 0, cfg.vocab)
+    eng = ServeEngine(cfg, params, max_batch=b, max_len=s + n)
+    logits, cache0 = eng._prefill(params, prompts, None)
+    cache = eng._rehome(cache0, b, s)
+    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+    step = jax.jit(lambda p, c, t, i: decode_step(cfg, p, c, t, i))
+    for p, loops in ((params, False), (cast, True)):
+        text = step.lower(p, cache, tok, jnp.int32(s)).as_text()
+        assert ("stablehlo.while" in text) == loops
+    wide, narrow = (step(p, cache, tok, jnp.int32(s)) for p in (params, cast))
+    for a, c in zip(jax.tree.leaves(wide), jax.tree.leaves(narrow)):
+        assert a.dtype == c.dtype and bool(jnp.array_equal(a, c))
+    tokens = [ServeEngine(cfg, p, max_batch=b, max_len=s + n).generate(
+        prompts, n_tokens=n).tokens for p in (params, cast)]
+    assert tokens[0] == tokens[1]
+
+
+def test_fused_weight_share_counts_weights_cast_apart():
+    """A program that writes a bf16 copy of one stacked weight (here as an
+    output) reads only the other weight as stored: the share is the other
+    weight's bytes over both."""
+    stack = {"w": jnp.ones((2, 8, 16)), "v": jnp.ones((2, 16, 32)),
+             "norm": jnp.ones((2, 8))}
+    x = jnp.ones((4, 8), jnp.bfloat16)
+
+    def program(s, x):
+        w, v = s["w"][1], s["v"][1]
+        y = x @ w.astype(x.dtype) @ v.astype(x.dtype)
+        return y, w.astype(jnp.bfloat16)
+
+    run = jax.jit(program).lower(stack, x).compile()
+    step = DecodeStep(enter=None, run=run)
+    assert fused_weight_share(step, {"stack": stack}) == 1 - 8 * 16 / (
+        8 * 16 + 16 * 32)
+    clean = jax.jit(lambda s, x: program(s, x)[0]).lower(stack, x).compile()
+    assert fused_weight_share(DecodeStep(None, clean),
+                              {"stack": stack}) == 1.0
+
+
 def _decoding_engine():
     cfg = get_config("olmo_1b", smoke=True)
     params = init_params(cfg, KEY)
@@ -102,7 +154,7 @@ def _decoding_engine():
 
 def test_compiled_decode_step_aliases_every_cache_byte():
     eng, cache, tok = _decoding_engine()
-    step, share, cache = eng._decoder(cache, tok, None)
+    step, share, _, cache = eng._decoder(cache, tok, None)
     cache_bytes = sum(x.nbytes for x in jax.tree.leaves(cache))
     assert step.memory_analysis().alias_size_in_bytes == cache_bytes
     assert share == 1.0
@@ -110,7 +162,7 @@ def test_compiled_decode_step_aliases_every_cache_byte():
 
 def test_decode_step_consumes_the_callers_cache():
     eng, rehomed, tok = _decoding_engine()
-    step, _, cache = eng._decoder(rehomed, tok, None)
+    step, _, _, cache = eng._decoder(rehomed, tok, None)
     _, out = step(eng.params, cache, tok, jnp.int32(8), None)
     assert all(x.is_deleted() for x in jax.tree.leaves(cache))
     assert not any(x.is_deleted() for x in jax.tree.leaves(out))
@@ -133,7 +185,7 @@ def test_decode_steady_runs_through_the_donated_step(arch):
         assert len(t.step_times) == 3 and all(x > 0 for x in t.step_times)
     eng.generate(prompts, n_tokens=3)
     assert len(eng._steps) == 1
-    [(_, share)] = eng._steps.values()
+    [(_, share, _)] = eng._steps.values()
     assert share == 1.0
 
 
@@ -255,7 +307,8 @@ def test_memory_threads_both_jitted_paths():
     tok = jnp.argmax(pre1[:, -1], -1).astype(jnp.int32)
     decoded = []
     for m in (m1, m2):      # the step donates its cache: one cache each
-        step, _, cache = eng._decoder(eng._rehome(cache1, 1, 4), tok, m)
+        step, _, _, cache = eng._decoder(eng._rehome(cache1, 1, 4), tok,
+                                         m)
         decoded.append(step(params, cache, tok, jnp.int32(4), m)[0])
     assert not jnp.allclose(*decoded)
     # and the end-to-end driver accepts it

@@ -23,7 +23,8 @@ from repro.core.pricing import FIELDS, _price, _roofline
 from repro.core.roofline import stack_terms
 from repro.kernels.pricing.ops import lower_f32
 from repro.models import decode_step, init_cache, init_params
-from repro.serve.engine import aliased_share, compile_decode
+from repro.serve.engine import (aliased_share, compile_decode,
+                                fused_weight_share)
 
 #: The dense grid's pricing chunk (``DSEEngine.price_chunk_rows``).
 CHUNK_ROWS = 65_536
@@ -107,14 +108,11 @@ def _computations(hlo: str) -> dict:
     return comps
 
 
-def test_olmo_1b_engine_decode_step_writes_the_cache_in_place(one_chip):
+@pytest.fixture(scope="module")
+def olmo_engine_step(one_chip):
     """The serving engine's decode executable at the benchmark's olmo_1b
-    widths (SwiGLU MLP), batch 16 over a 1,152-slot cache: the only copies
-    or fusions that output a buffer of one layer's cache or of the stacked
-    cache are the two row writes, each an in-place dynamic-update-slice,
-    and every byte of the cache is aliased from argument to output. The
-    executable's own entry layouts are the default ones, which JAX's
-    persistent compilation cache gives back as they were compiled."""
+    widths (SwiGLU MLP, f32 weights, bf16 activations), batch 16 over a
+    1,152-slot cache, with the shapes it was compiled for."""
     cfg = dataclasses.replace(get_config("olmo_1b"), gated=True)
     batch, max_len = 16, 1152
 
@@ -128,7 +126,21 @@ def test_olmo_1b_engine_decode_step_writes_the_cache_in_place(one_chip):
     cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, batch, max_len)))
     token = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    step = compile_decode(cfg, params, cache, token, pos)
+    return cfg, params, cache, compile_decode(cfg, params, cache, token, pos)
+
+
+def test_olmo_1b_engine_decode_step_writes_the_cache_in_place(
+        olmo_engine_step):
+    """The engine's olmo_1b decode executable: the only copies or fusions
+    that output a buffer of one layer's cache or of the stacked cache are
+    the row writes, two a layer (the layer loop is unrolled, as the
+    weights are f32 under bf16 activations), each an in-place
+    dynamic-update-slice, and every byte of the cache is aliased from
+    argument to output. The executable's own entry layouts are the
+    default ones, which JAX's persistent compilation cache gives back as
+    they were compiled."""
+    cfg, _, cache, step = olmo_engine_step
+    batch, max_len = cache["k"].shape[2:4]
 
     assert aliased_share(step, cache) == 1.0
     for fmt in jax.tree.leaves(step.run.input_formats[0][1]):
@@ -147,11 +159,43 @@ def test_olmo_1b_engine_decode_step_writes_the_cache_in_place(one_chip):
             if m and m.group(2) in shapes:
                 writes.append((m.group(1), m.group(3),
                                re.search(r"calls=%([\w.-]+)", line)))
-    assert len(writes) == 2, writes
+    assert len(writes) == 2 * cfg.n_layers, writes
     for name, op, called in writes:
         assert op == "fusion" and called, name
         root = [ln for ln in comps[called.group(1)] if ln.startswith("ROOT")]
         assert " dynamic-update-slice(" in root[0], (name, root)
+
+
+def test_olmo_1b_engine_decode_step_reads_its_f32_weights_in_its_dots(
+        olmo_engine_step):
+    """The engine's olmo_1b decode executable makes no bf16 copy of a
+    weight: no op outside a fusion's body outputs a bf16 array of a
+    weight's shape, the whole stack or one layer's (``fused_weight_share``
+    reads 1.0); each of the seven stacked f32 weights is an operand of one
+    dot fusion a layer, which slices and casts it inside; and the
+    temporaries stay under 0.5 GB (the seven stacks' hoisted bf16 copies
+    took 2.15 GB)."""
+    cfg, params, _, step = olmo_engine_step
+    assert fused_weight_share(step, params) == 1.0
+    stack = jax.tree.leaves(params["stack"])
+    comps = _computations(step.run.as_text())
+    [entry] = [n for n in comps if n.startswith("main")]
+    stacked = {"f32[{}]".format(",".join(map(str, w.shape))) for w in stack}
+    params_in = [m.group(1) for line in comps[entry]
+                 for m in [re.match(r"%(\S+) = (\w+\[[\d,]*\])\S* "
+                                    r"parameter\(", line)]
+                 if m and m.group(2) in stacked]
+    assert len(params_in) == len(stack) == 7
+    for p in params_in:
+        users = [line for line in comps[entry]
+                 if re.search(rf"[(, ]%{re.escape(p)}[,)]", line)]
+        assert len(users) == cfg.n_layers, (p, users)
+        for line in users:
+            called = re.search(r" fusion\(.*calls=%([\w.-]+)", line)
+            assert called, line
+            body = comps[called.group(1)]
+            assert any(" convolution(" in b or " dot(" in b for b in body)
+    assert step.run.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
 
 
 @pytest.mark.parametrize("arch", ["olmoe_1b_7b", "jamba_v01_52b"])

@@ -2,6 +2,7 @@
 ``ServeEngine.generate``, and the layer names on the model's device ops."""
 from __future__ import annotations
 
+import dataclasses
 import gc
 import re
 import time
@@ -172,6 +173,29 @@ def test_generate_counts_the_aliased_cache_share(tmp_path):
     tracing.reset()
     assert "serve.cache_aliased" not in prefill_only
     assert counters["serve.cache_aliased"] == 2.0
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_generate_counts_the_fused_weight_share(tmp_path, dtype):
+    """``serve.weights_fused`` adds 1.0 (every matmul reads its weight as
+    stored) once per decoding ``generate`` call under a recording
+    profiler, for f32 weights under bf16 activations (cast inside each
+    dot) and under f32 ones (no cast), and nothing without one. (The CPU
+    widens bf16 weights to f32 for its dots, apart from them, so a
+    bf16-weight config reads 0 here; on the TPU's compiler it reads 1.0,
+    ``test_granite_compile.py``.)"""
+    cfg = dataclasses.replace(get_config("olmo_1b", smoke=True),
+                              dtype=dtype)
+    eng = ServeEngine(cfg, init_params(cfg, KEY), max_batch=2, max_len=32)
+    prompts = jax.random.randint(KEY, (2, 8), 0, cfg.vocab)
+    tracing.reset()
+    eng.generate(prompts, n_tokens=3)                # compiles, not recorded
+    assert tracing.snapshot()["counters"] == {}
+    with jax.profiler.trace(str(tmp_path)):
+        eng.generate(prompts, n_tokens=3)
+        counters = tracing.snapshot()["counters"]
+    tracing.reset()
+    assert counters["serve.weights_fused"] == 1.0
 
 
 SCOPES = ("layers", "attention", "kv_cache", "mlp", "lm_head", "fill_cache")
